@@ -33,8 +33,8 @@ import numpy as np
 # queue produces under load; r2 measured single-block 1024-query batches.
 CORPUS = int(os.environ.get("BENCH_CORPUS", "20000"))
 # the number of record is a MEDIAN of BENCH_RUNS timed batches (r4 verdict:
-# a single-sample bench cannot distinguish a 10% regression from the
-# documented host/tunnel variance); per-run rates ride the stderr line
+# a single-sample bench cannot distinguish a 10% regression from
+# run-to-run variance); per-run rates ride the stderr line
 BENCH_RUNS = int(os.environ.get("BENCH_RUNS", "3"))
 QUERIES = int(os.environ.get("BENCH_QUERIES", "8192"))
 # pre-size the corpus so the warm-up and timed batches (appended then
@@ -1596,8 +1596,6 @@ def capacity_bench() -> dict:
 
 _MESH_CHILD = r'''
 import json, os, time
-from sesam_duke_microservice_tpu.utils.virtual_mesh import force_cpu_platform
-force_cpu_platform()
 from bench import bench_schema, stresstest_records
 from sesam_duke_microservice_tpu.core.config import MatchTunables
 from sesam_duke_microservice_tpu.engine.device_matcher import (

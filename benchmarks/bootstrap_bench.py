@@ -18,7 +18,7 @@ Usage::
 
     python benchmarks/bootstrap_bench.py [--rows 1000000] [--batch 8192]
 
-Prints one JSON line with rss/frames stats for BASELINE.md.
+Prints one JSON line with rss/frames stats.
 """
 
 from __future__ import annotations

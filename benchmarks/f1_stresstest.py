@@ -146,7 +146,7 @@ def stresstest_schema(ssn_exact: bool = False):
     the Bayes product crosses the threshold — FPs every engine emits identically (host-exact verified),
     i.e. a schema artifact, not a matcher one.  Large-corpus quality runs
     use --ssn-exact so precision measures the matcher.  The default stays
-    QGram for continuity with the 10k-scale numbers in BASELINE.md.
+    QGram, the reference stresstest's comparator.
     """
     from sesam_duke_microservice_tpu.core import comparators as C
     from sesam_duke_microservice_tpu.core.config import DukeSchema

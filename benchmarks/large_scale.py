@@ -54,12 +54,6 @@ def _reexec_on_virtual_mesh(n_devices: int) -> None:
 def run_sharded(args) -> None:
     import jax
 
-    if os.environ.get("_LS_SHARDED_INNER") == "1":
-        from sesam_duke_microservice_tpu.utils.virtual_mesh import (
-            force_cpu_platform,
-        )
-
-        force_cpu_platform()
     if (len(jax.devices()) < args.devices
             and os.environ.get("_LS_SHARDED_INNER") != "1"):
         _reexec_on_virtual_mesh(args.devices)

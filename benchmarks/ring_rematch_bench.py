@@ -38,8 +38,6 @@ def _reexec(argv, n_devices):
 
     env = virtual_mesh_env(n_devices, "_RING_BENCH_INNER")
     code = (
-        "from sesam_duke_microservice_tpu.utils.virtual_mesh import "
-        "force_cpu_platform; force_cpu_platform(); "
         "import runpy, sys; sys.argv = %r; "
         "runpy.run_path(%r, run_name='__main__')"
         % ([sys.argv[0]] + argv, os.path.abspath(__file__))

@@ -245,19 +245,27 @@ class _MeshProgramLift:
         the verdicts are bit-identical (tests/test_mesh_parity.py)."""
         import jax.numpy as jnp
 
-        from ..parallel.sharded import build_replicated_gather
+        from ..parallel.sharded import (
+            build_replicated_gather,
+            replicated_program,
+        )
 
         gather = getattr(self, "_dd_gather_fn", None)
         if gather is None:
             gather = build_replicated_gather(self.index.mesh)
             self._dd_gather_fn = gather
+            self._dd_programs = {}
+        program = self._dd_programs.get(fn)
+        if program is None:
+            program = self._dd_programs[fn] = replicated_program(
+                self.index.mesh, fn)
         q, k = top_index.shape
         rows = jnp.clip(top_index, 0).reshape(-1)
         gathered = gather(cfeats, rows)
         self._dd_gathers += 1
         self._dd_gather_rows += int(q * k)
         ident = jnp.arange(q * k, dtype=jnp.int32).reshape(q, k)
-        return fn(qfeats, gathered, query_row_j, ident)
+        return program(qfeats, gathered, query_row_j, ident)
 
     def _sds(self, shape, dtype, family: str = "corpus"):
         """Mesh-annotated lowering avals: corpus-family tensors carry the

@@ -1,16 +1,21 @@
 """ctypes bindings for the C++ host comparators (duke_native.cpp).
 
-Loads ``libduke_native.so`` from this directory, compiling it with g++ on
-first use (no pybind11 in the image; plain C ABI + ctypes).  Every entry
-point degrades gracefully: if the toolchain or library is unavailable —
-or ``DUKE_TPU_NATIVE=0`` — ``available()`` is False and callers (the
-comparators in core/comparators.py) keep their pure-Python path, which
-doubles as the parity oracle (tests/test_native.py).
+Loads ``libduke_native.<hash>.so`` from this directory, compiling it with
+g++ on first use (no pybind11 in the image; plain C ABI + ctypes).  The
+file name carries a hash of ``duke_native.cpp``, so a library built from
+other source (an ignored ``.so`` copied along with a checkout) is never
+loaded.  Every entry point degrades gracefully: if the toolchain or
+library is unavailable — or ``DUKE_TPU_NATIVE=0`` — ``available()`` is
+False and callers (the comparators in core/comparators.py) keep their
+pure-Python path, which doubles as the parity oracle
+(tests/test_native.py).
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -25,21 +30,30 @@ logger = logging.getLogger("duke-tpu-native")
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "duke_native.cpp")
-_LIB = os.path.join(_HERE, "libduke_native.so")
+
+
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"libduke_native.{digest}.so")
+
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
+def _build(lib: str) -> bool:
     # compile to a private temp name, then rename: os.rename is atomic on
     # POSIX, so a concurrent process never dlopens a half-written library
-    tmp = _LIB + f".tmp.{os.getpid()}"
+    tmp = lib + f".tmp.{os.getpid()}"
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", tmp, _SRC]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.rename(tmp, _LIB)
+        os.rename(tmp, lib)
+        for stale in glob.glob(os.path.join(_HERE, "libduke_native*.so")):
+            if stale != lib:
+                os.unlink(stale)
         return True
     except (OSError, subprocess.SubprocessError) as e:
         logger.warning("native comparator build failed (%s); using pure Python", e)
@@ -60,14 +74,13 @@ def _load() -> Optional[ctypes.CDLL]:
         _tried = True
         if not env_flag("DUKE_TPU_NATIVE", True):
             return None
-        if (not os.path.exists(_LIB)
-                or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
-            if not _build():
-                return None
+        path = _lib_path()
+        if not os.path.exists(path) and not _build(path):
+            return None
         try:
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(path)
         except OSError as e:
-            logger.warning("could not load %s (%s); using pure Python", _LIB, e)
+            logger.warning("could not load %s (%s); using pure Python", path, e)
             return None
         u32p = ctypes.POINTER(ctypes.c_uint32)
         i64p = ctypes.POINTER(ctypes.c_int64)

@@ -450,7 +450,7 @@ def retrieval_scan(q_emb, corpus_emb, corpus_valid, corpus_deleted,
     row indices (``row_offset`` as in scan_topk for sharded use).
 
     The scan chunk is widened to ``DEVICE_ANN_RETRIEVAL_CHUNK`` (default
-    65536; see BASELINE.md r5 retrieval table) when the corpus allows:
+    65536) when the corpus allows:
     the matmul is so cheap per row that per-step overhead (top-C merge,
     scan bookkeeping) dominates with small chunks.  Capacities are
     power-of-2 multiples of the base chunk, so any power-of-2 widening

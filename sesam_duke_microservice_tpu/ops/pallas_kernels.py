@@ -31,9 +31,8 @@ Kernel inventory:
     Dice).
   * ``jaro_winkler_sim_tiles`` — Jaro-Winkler over all pairs via matched-
     position uint32 bitmasks (greedy window matching + lowest-bit
-    transposition walk); 31x the flat XLA path end-to-end at the
-    production scan config (BASELINE.md).  Differentially tested against
-    the scalar comparator oracle.
+    transposition walk).  Differentially tested against the scalar
+    comparator oracle.
 
 Enabling: ``pallas_enabled()`` — env ``DUKE_TPU_PALLAS`` ("1" force on,
 "0" force off); default on only when the active JAX backend is TPU.  On
@@ -48,23 +47,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..telemetry.env import env_flag
 
-try:  # pltpu is importable on all platforms; guard anyway
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover - non-TPU builds without pltpu
-    pltpu = None
-    _VMEM = None
+_VMEM = pltpu.VMEM
 
 
 def _backend() -> str:
-    try:
-        return jax.default_backend()
-    except Exception:  # pragma: no cover
-        return "cpu"
+    return jax.default_backend()
 
 
 def pallas_enabled() -> bool:

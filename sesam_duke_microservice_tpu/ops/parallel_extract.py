@@ -102,9 +102,10 @@ def _shutdown() -> None:
 
 
 def _worker_init() -> None:
-    # workers never touch an accelerator; belt-and-braces in case a
-    # transitive import ever reaches jax in a future refactor
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")  # dukecheck: ignore[DK301] spawned-worker env WRITE, not a knob read
+    # workers never touch an accelerator: the parent holds the chip, and
+    # a worker that reached for it would fail or hang.  Forced, not
+    # defaulted — a parent started with JAX_PLATFORMS=tpu passes that on
+    os.environ["JAX_PLATFORMS"] = "cpu"  # dukecheck: ignore[DK301] spawned-worker env WRITE, not a knob read
 
 
 def _worker_extract(task) -> None:

@@ -1,4 +1,4 @@
-"""Sharded corpus scoring: constraint-driven GSPMD over a device mesh.
+"""Sharded corpus scoring over a device mesh.
 
 Data layout (the scaling-book recipe — pick a mesh, annotate shardings, let
 XLA insert collectives):
@@ -12,14 +12,13 @@ XLA insert collectives):
     Q*K*D*8 bytes over ICI, not the candidate matrix) and reduced to the
     global top-K on every device.
 
-The program is a plain ``jit`` over ``NamedSharding``-placed inputs: the
-per-shard scan is expressed as ``vmap`` over a leading shard axis pinned to
-the mesh with ``with_sharding_constraint`` and the merge as a constraint to
-replicated layout, so the partitioner — not a hand-written ``shard_map``
-closure — chooses the collectives.  The partition rules per tensor family
-live in ``PARTITION_RULES`` and are shared by the placement helpers here,
-the in-program constraints, and the IVF placers in
-``engine/sharded_matcher.py``.
+The program is a ``jit`` over ``NamedSharding``-placed inputs.  The
+brute-force per-shard scan is a ``shard_map`` (it holds Pallas kernels,
+and GSPMD cannot partition a Mosaic call); the merge is a constraint to
+replicated layout, so the partitioner chooses that collective.  The
+partition rules per tensor family live in ``PARTITION_RULES`` and are
+shared by the placement helpers here, the in-program constraints, and
+the IVF placers in ``engine/sharded_matcher.py``.
 
 This scales the O(Q x N) pair-scoring work linearly in device count while
 the communication stays O(Q x K x D): the framework's counterpart of
@@ -139,33 +138,46 @@ def build_sharded_scorer(
     sharded).  Row indices in ``top_index`` and ``query_row`` are global.
     """
     pair_logits = S.build_pair_logits(plan)
-    ndev = mesh.size
+    corpus = P(SHARD_AXIS)
+
+    # the per-shard scan is a shard_map, not a GSPMD-partitioned vmap:
+    # it holds Mosaic kernels, which the partitioner cannot split
+    def local_scan(qfeats, cf, cv, cd, cg, query_group, query_row,
+                   min_logit):
+        row_offset = lax.axis_index(SHARD_AXIS) * cv.shape[0]
+        top_logit, top_index, count = S.scan_topk(
+            pair_logits, qfeats, cf, cv, cd, cg,
+            query_group, query_row, min_logit,
+            chunk=chunk, top_k=top_k, group_filtering=group_filtering,
+            row_offset=row_offset,
+        )
+        return top_logit[None], top_index[None], count[None]
+
+    scan = jax.shard_map(
+        local_scan, mesh=mesh,
+        in_specs=(P(), corpus, corpus, corpus, corpus, P(), P(), P()),
+        out_specs=(corpus, corpus, corpus), check_vma=False,
+    )
 
     def score_shard(qfeats, corpus_feats, corpus_valid, corpus_deleted,
                     corpus_group, query_group, query_row, min_logit):
-        split = shardwise(mesh)
-        repl = replicated(mesh)
-        cf = jax.tree_util.tree_map(split, corpus_feats)
-        cv = split(corpus_valid)
-        cd = split(corpus_deleted)
-        cg = split(corpus_group)
-        local_cap = corpus_valid.shape[0] // ndev
-        offsets = shard_offsets(mesh, local_cap)
-
-        def one_shard(cf, cv, cd, cg, row_offset):
-            return S.scan_topk(
-                pair_logits, qfeats, cf, cv, cd, cg,
-                query_group, query_row, min_logit,
-                chunk=chunk, top_k=top_k, group_filtering=group_filtering,
-                row_offset=row_offset,
-            )
-
-        top_logit, top_index, count = jax.vmap(one_shard)(cf, cv, cd, cg, offsets)
+        top_logit, top_index, count = scan(
+            qfeats, corpus_feats, corpus_valid, corpus_deleted,
+            corpus_group, query_group, query_row, min_logit)
         out_logit, out_index, _ = merge_topk(mesh, top_logit, top_index, top_k)
-        total_count = repl(count.sum(axis=0))
+        total_count = replicated(mesh)(count.sum(axis=0))
         return out_logit, out_index, total_count
 
     return jax.jit(score_shard)
+
+
+def replicated_program(mesh: Mesh, fn: Callable) -> Callable:
+    """``fn``, a single-device program, run on every device of the mesh
+    over replicated operands.  A program holding Mosaic kernels cannot be
+    partitioned by GSPMD, not even to replicate it; inside a shard_map
+    each device runs it whole."""
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                                 check_vma=False))
 
 
 def build_replicated_gather(mesh: Mesh) -> Callable:

@@ -48,8 +48,14 @@ from ..telemetry.registry import FamilySnapshot
 
 logger = logging.getLogger("jit-cache")
 
+# Without JAX_COMPILATION_CACHE_DIR the caches live at one fixed,
+# git-ignored path inside the checkout: the path is part of the cache key,
+# so it must not move between runs, and the program writes nothing outside
+# its checkout.
 _DEFAULT = os.path.join(
-    os.path.expanduser("~"), ".cache", "sesam_duke_tpu_xla"
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))),
+    ".jax_cache",
 )
 
 # AOT-load outcome children pre-resolved at import (closed label set,
@@ -79,11 +85,18 @@ def record_aot_reject(n: int = 1) -> None:
     _AOT_REJECT.inc(n)
 
 
-def enable_persistent_cache(path: Optional[str] = None) -> Optional[str]:
-    """Point jax at an on-disk compilation cache; returns the path used.
+def cache_dir() -> str:
+    """``JAX_COMPILATION_CACHE_DIR`` when set, else the checkout's
+    ``.jax_cache``."""
+    return env_str("JAX_COMPILATION_CACHE_DIR") or _DEFAULT
 
-    Safe to call multiple times; a failure (read-only fs, old jax) only
-    logs — the cache is an optimization, never a requirement.
+
+def enable_persistent_cache() -> Optional[str]:
+    """Point jax at the on-disk compilation cache (``cache_dir()``);
+    returns the path used.
+
+    Safe to call multiple times; a failure (read-only fs) only logs —
+    the cache is an optimization, never a requirement.
 
     ``DUKE_JIT_CACHE_MIN_SECS`` sets the persistence floor (jax's
     ``jax_persistent_cache_min_compile_time_secs``).  The historical
@@ -93,7 +106,7 @@ def enable_persistent_cache(path: Optional[str] = None) -> Optional[str]:
     """
     import jax
 
-    path = path or env_str("JAX_COMPILATION_CACHE_DIR") or _DEFAULT
+    path = cache_dir()
     try:
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
@@ -102,7 +115,7 @@ def enable_persistent_cache(path: Optional[str] = None) -> Optional[str]:
             env_float("DUKE_JIT_CACHE_MIN_SECS", 1.0),
         )
         return path
-    except Exception as exc:  # pragma: no cover - depends on fs/jax version
+    except OSError as exc:  # pragma: no cover - depends on the fs
         logger.warning("persistent compilation cache disabled: %s", exc)
         return None
 
@@ -117,7 +130,7 @@ def aot_enabled() -> bool:
 
 
 def aot_dir() -> str:
-    return env_str("DUKE_AOT_DIR") or os.path.join(_DEFAULT, "aot")
+    return env_str("DUKE_AOT_DIR") or os.path.join(cache_dir(), "aot")
 
 
 _CODE_FP: Optional[str] = None
@@ -193,13 +206,25 @@ def mesh_fingerprint(mesh) -> dict:
     return doc
 
 
+def _devices(ids: List[int]) -> list:
+    """The visible devices with these ids, in this order (KeyError when
+    one is missing — the caller's reject path)."""
+    import jax
+
+    by_id = {d.id: d for d in jax.devices()}
+    return [by_id[i] for i in ids]
+
+
 class AotStore:
     """On-disk store of serialized compiled executables.
 
     One file per (plan, shape, backend, version) key: the key dict is
     canonical-JSON-hashed into the filename, and the file holds a pickle
-    of ``(key, payload, in_tree, out_tree)`` where payload/trees come
-    from ``jax.experimental.serialize_executable.serialize``.  Writes
+    of ``(key, payload, in_tree, out_tree, device_ids)`` where
+    payload/trees come from ``jax.experimental.serialize_executable
+    .serialize`` and ``device_ids`` are the devices the executable was
+    compiled for: it is loaded onto exactly those, not onto every
+    visible device.  Writes
     are crash-atomic (``utils.atomicio``); concurrent savers of the same
     key race benignly (identical content, last replace wins).  No lock:
     load/save are pure file ops keyed by immutable content.
@@ -232,6 +257,9 @@ class AotStore:
 
         try:
             payload, in_tree, out_tree = se.serialize(compiled)
+            device_ids = [d.id for d in
+                          compiled._executable._unloaded_executable
+                          .device_list]
             # round-trip validation BEFORE the write: an executable whose
             # XLA compile was served from jax's persistent compilation
             # cache serializes THIN (the payload references jit symbols
@@ -240,9 +268,12 @@ class AotStore:
             # restart; refusing the save leaves the entry to a fresh
             # compile instead (the warm thread compiles cache-bypassed
             # for exactly this reason).
-            se.deserialize_and_load(payload, in_tree, out_tree)
+            se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=_devices(device_ids))
             blob = pickle.dumps(
-                (self._key_doc(key), payload, in_tree, out_tree),
+                (self._key_doc(key), payload, in_tree, out_tree,
+                 device_ids),
                 protocol=pickle.HIGHEST_PROTOCOL,
             )
             os.makedirs(self.root, exist_ok=True)
@@ -270,10 +301,13 @@ class AotStore:
             _AOT_MISS.inc()
             return None
         try:
-            stored_key, payload, in_tree, out_tree = pickle.loads(blob)
+            (stored_key, payload, in_tree, out_tree,
+             device_ids) = pickle.loads(blob)
             if stored_key != self._key_doc(key):
                 raise ValueError("stored key mismatch (hash collision?)")
-            loaded = se.deserialize_and_load(payload, in_tree, out_tree)
+            loaded = se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=_devices(device_ids))
         except Exception as exc:
             _AOT_REJECT.inc()
             logger.warning(
@@ -346,6 +380,12 @@ class SharedLadderRegistry:
             entry.refs -= 1
             if entry.refs <= 0:
                 self._entries.pop(entry.key, None)
+
+    def executables(self) -> List[object]:
+        """Every registered executable across the live ladders."""
+        with self._lock:
+            return [exe for e in self._entries.values()
+                    for exe in list(e.map.values())]
 
     def stats(self) -> Dict[str, int]:
         """{ladders, refs, executables} — bench/debug surface."""

@@ -245,6 +245,26 @@ def test_jit_cache_min_secs_knob(tmp_path, monkeypatch):
     assert jax.config.jax_persistent_cache_min_compile_time_secs == 1.0
 
 
+def test_caches_follow_jax_compilation_cache_dir(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR places both caches; without it they sit
+    at one fixed, git-ignored directory inside the checkout."""
+    from sesam_duke_microservice_tpu.utils.jit_cache import (
+        aot_dir,
+        cache_dir,
+    )
+
+    monkeypatch.delenv("DUKE_AOT_DIR", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert cache_dir() == str(tmp_path)
+    assert aot_dir() == os.path.join(str(tmp_path), "aot")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cache_dir() == os.path.join(checkout, ".jax_cache")
+    assert aot_dir() == os.path.join(checkout, ".jax_cache", "aot")
+    with open(os.path.join(checkout, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
 def test_aot_enabled_knob(monkeypatch):
     monkeypatch.delenv("DUKE_AOT", raising=False)
     assert aot_enabled() is True

@@ -63,3 +63,44 @@ def test_two_process_distributed_init_and_sharded_scoring():
     for rc, out, err in last:
         assert rc == 0, f"child failed (rc={rc}):\n{err[-4000:]}"
         assert "DCN_OK" in out, (out, err[-2000:])
+
+
+def _stub_join(monkeypatch, outcome):
+    import jax
+
+    from sesam_duke_microservice_tpu.parallel import multihost
+
+    calls = []
+
+    def join(*args, **kwargs):
+        calls.append(kwargs)
+        if isinstance(outcome, Exception):
+            raise outcome
+
+    monkeypatch.delenv("JAX_COORDINATOR_ADDRESS", raising=False)
+    monkeypatch.delenv("MEGASCALE_COORDINATOR_ADDRESS", raising=False)
+    monkeypatch.setattr(jax.distributed, "initialize", join)
+    monkeypatch.setattr(multihost, "_enable_cpu_collectives", lambda: None)
+    return multihost, calls
+
+
+def test_single_host_tpu_vm_joins_nothing(monkeypatch):
+    """The v5e machines set TPU_WORKER_HOSTNAMES=localhost and
+    TPU_WORKER_ID=0: a single-process run, not a job to join."""
+    multihost, calls = _stub_join(monkeypatch, None)
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    monkeypatch.setenv("TPU_WORKER_ID", "0")
+    assert multihost.initialize() is False
+    assert calls == []
+
+
+def test_multi_host_slice_join_failure_raises(monkeypatch):
+    """A slice that names several hosts joins; a failed join is not
+    swallowed into a single-process run."""
+    import pytest
+
+    multihost, calls = _stub_join(monkeypatch, RuntimeError("unreachable"))
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "host0,host1")
+    with pytest.raises(RuntimeError, match="unreachable"):
+        multihost.initialize()
+    assert calls == [{}]

@@ -173,3 +173,15 @@ def test_embed_batch_matches_numpy_oracle():
     finally:
         E._native_embed = saved
     np.testing.assert_allclose(nat, ref, atol=1e-6)
+
+
+def test_library_is_keyed_by_source_hash(tmp_path, monkeypatch):
+    """A library built from other source is never loaded: the file name
+    moves with the content of duke_native.cpp, not with its mtime."""
+    src = tmp_path / "duke_native.cpp"
+    src.write_text("int f() { return 1; }")
+    monkeypatch.setattr(native, "_SRC", str(src))
+    first = native._lib_path()
+    assert native._lib_path() == first
+    src.write_text("int f() { return 2; }")
+    assert native._lib_path() != first

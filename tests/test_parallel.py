@@ -175,3 +175,23 @@ class TestMultihost:
 
         mesh = global_corpus_mesh()
         assert mesh.size == jax.device_count()
+
+
+def test_virtual_mesh_env_is_enough_to_pin_the_cpu():
+    """A child started with ``virtual_mesh_env`` sees only the virtual CPU
+    devices: JAX_PLATFORMS=cpu set before the interpreter starts needs no
+    in-process platform override."""
+    import subprocess
+    import sys
+
+    from sesam_duke_microservice_tpu.utils.virtual_mesh import (
+        virtual_mesh_env,
+    )
+
+    env = virtual_mesh_env(4, "_TEST_INNER")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; d = jax.devices(); print(d[0].platform, len(d))"],
+        env=env, capture_output=True, text=True, timeout=120, check=True,
+    ).stdout.split()
+    assert out == ["cpu", "4"]

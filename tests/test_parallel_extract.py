@@ -5,8 +5,8 @@ produce BIT-IDENTICAL tensors to the serial extractor for every feature
 kind, including the ANN embedding, uint16 char units, and auto-width
 specs.  Throughput is environment-bound (this CI host exposes ONE core,
 where any process pool loses by construction — the r4 finding); the
-speedup claim belongs to multi-core deployments and is documented in
-BASELINE.md, not asserted here.
+speedup claim belongs to multi-core deployments and is not asserted
+here.
 """
 
 import numpy as np
