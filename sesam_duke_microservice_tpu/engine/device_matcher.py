@@ -2162,7 +2162,7 @@ class _ScorerCache:
                 ctx = (self._cache_bypass() if store is not None
                        else contextlib.nullcontext())
                 t_compile = time.monotonic()
-                with ctx:
+                with ctx, tracing.span("scorer.compile", annotate=True):
                     compiled = self._lower_one(
                         row_feats, cap_i, bucket, group_filtering,
                         from_rows=from_rows,
@@ -2217,8 +2217,9 @@ class _ScorerCache:
             # pair makes recompile storms visible on /metrics.
             record_compile()
             t_compile = time.monotonic()
-            self._scorers[key] = self._build(top_k, group_filtering,
-                                             from_rows)
+            with tracing.span("scorer.compile", annotate=True):
+                self._scorers[key] = self._build(top_k, group_filtering,
+                                                 from_rows)
             costs.note_compile(time.monotonic() - t_compile)
         else:
             record_cache_hit()
@@ -2604,7 +2605,6 @@ class DeviceProcessor:
         # a frontend that aborts mid-pass (listener exception, OOM) has
         # entered fewer collective programs than the followers it just
         # instructed — latch before propagating (advisor r4 medium)
-        match_ns = time.monotonic_ns()
         with dispatch.latch_on_failure(
             d, "frontend scoring pass aborted after broadcast"
         ):
@@ -2615,9 +2615,6 @@ class DeviceProcessor:
         score_dt = self.stats.compare_seconds - compare0
         self.phases.observe(PHASE_RETRIEVE, retrieve_dt)
         self.phases.observe(PHASE_SCORE, score_dt)
-        # device-program resolve and host finalization interleave across
-        # the double-buffered blocks: the shared aggregate-span layout
-        tracing.add_phase_spans(match_ns, retrieve_dt, score_dt)
         t_persist = time.monotonic()
         with tracing.span(PHASE_PERSIST, annotate=True):
             for listener in self.listeners:
@@ -2668,62 +2665,71 @@ class DeviceProcessor:
         for bi, block in enumerate(blocks):
             t1 = time.monotonic()
             nxt = None
-            if bi + 1 < len(blocks):
-                nxt = self._scorers.dispatch_block(
-                    blocks[bi + 1], group_filtering=self.group_filtering
-                )
-            with trace_batch(f"score_block[{len(block)}]"):
-                result = resolve_block(pending)
+            # the retrieve/score spans cover exactly what the phase
+            # timers below measure: the host's wait for block bi (with
+            # block bi+1's dispatch), then its finalization
+            with tracing.span(PHASE_RETRIEVE, annotate=True):
+                if bi + 1 < len(blocks):
+                    nxt = self._scorers.dispatch_block(
+                        blocks[bi + 1],
+                        group_filtering=self.group_filtering,
+                    )
+                with trace_batch(f"score_block[{len(block)}]"):
+                    result = resolve_block(pending)
             pending = nxt
             t2 = time.monotonic()
             self.stats.retrieval_seconds += t2 - t1
 
             if not self.finalize_survivors:
                 continue
-            if self.finalizer.device:
-                # dd survivor rescore (ISSUE 12): one more collective-
-                # free device program over the resolved (Q, K) pair
-                # list; engine.finalize certifies verdicts against it
-                # and skips the host compare for certified rejects
-                result.dd = self._scorers.dd_rescore(result)
-            # parallel host finalization: workers compute the exact f64
-            # rescores (and the decisive-band skips) per query; events
-            # then emit HERE, serially and in query order, so listener
-            # streams and link rows are identical to the serial path at
-            # any DUKE_FINALIZE_THREADS (engine.finalize)
-            outcomes = self.finalizer.finalize_block(self, block, result)
-            for qi, (record, out) in enumerate(zip(block, outcomes)):
-                for event, candidate, prob in out.events:
-                    self._emit(event, record, candidate, prob)
-                if not out.events:
-                    for listener in self.listeners:
-                        listener.no_match_for(record)
-                if out.decisions:
-                    # drift monitors + sampled/latched ring records, on
-                    # the serial event-coordinator thread (single-writer)
-                    self.decisions.observe(
-                        record, out.decisions, prune=out.prune,
-                        margin=out.margin, host_bound=out.host_bound,
-                    )
-                self.stats.records_processed += 1
-                self.stats.candidates_retrieved += out.survivors
-                self.stats.pairs_rescored += out.rescored
-                self.stats.pairs_skipped += out.skipped
-                self.stats.pairs_device_certified += out.device_certified
-                self.stats.dd_residue_margin += out.residue_margin
-                self.stats.dd_residue_kind += out.residue_kind
-                self.stats.dd_residue_truncation += out.residue_truncation
-                if self.exhaustive:
-                    # the device ran the exact comparator kernels against
-                    # every live corpus row for this query
-                    self.stats.pairs_compared += live_rows
-                else:
-                    # ANN: exact kernels ran only on the retrieved top-C
-                    # (the retrieval matmul touches every row, but that is
-                    # blocking work, not pair comparison)
-                    self.stats.pairs_compared += int(
-                        (result.top_index[qi] >= 0).sum()
-                    )
+            with tracing.span(PHASE_SCORE, annotate=True):
+                if self.finalizer.device:
+                    # dd survivor rescore: one more collective-
+                    # free device program over the resolved (Q, K) pair
+                    # list; engine.finalize certifies verdicts against it
+                    # and skips the host compare for certified rejects
+                    result.dd = self._scorers.dd_rescore(result)
+                # parallel host finalization: workers compute the exact
+                # f64 rescores (and the decisive-band skips) per query;
+                # events then emit HERE, serially and in query order, so
+                # listener streams and link rows are identical to the
+                # serial path at any DUKE_FINALIZE_THREADS
+                # (engine.finalize)
+                outcomes = self.finalizer.finalize_block(self, block, result)
+                stats = self.stats
+                for qi, (record, out) in enumerate(zip(block, outcomes)):
+                    for event, candidate, prob in out.events:
+                        self._emit(event, record, candidate, prob)
+                    if not out.events:
+                        for listener in self.listeners:
+                            listener.no_match_for(record)
+                    if out.decisions:
+                        # drift monitors + sampled/latched ring records,
+                        # on the serial event-coordinator thread
+                        # (single-writer)
+                        self.decisions.observe(
+                            record, out.decisions, prune=out.prune,
+                            margin=out.margin, host_bound=out.host_bound,
+                        )
+                    stats.records_processed += 1
+                    stats.candidates_retrieved += out.survivors
+                    stats.pairs_rescored += out.rescored
+                    stats.pairs_skipped += out.skipped
+                    stats.pairs_device_certified += out.device_certified
+                    stats.dd_residue_margin += out.residue_margin
+                    stats.dd_residue_kind += out.residue_kind
+                    stats.dd_residue_truncation += out.residue_truncation
+                    if self.exhaustive:
+                        # the device ran the exact comparator kernels
+                        # against every live corpus row for this query
+                        stats.pairs_compared += live_rows
+                    else:
+                        # ANN: exact kernels ran only on the retrieved
+                        # top-C (the retrieval matmul touches every row,
+                        # but that is blocking work, not pair comparison)
+                        stats.pairs_compared += int(
+                            (result.top_index[qi] >= 0).sum()
+                        )
             self.stats.compare_seconds += time.monotonic() - t2
 
     def _emit(self, event: str, r1: Record, r2: Record, prob: float) -> None:

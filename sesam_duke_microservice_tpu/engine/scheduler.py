@@ -412,9 +412,14 @@ class IngestScheduler:
     def _dispatch_loop(self) -> None:
         while True:
             with self._cv:
-                while not self._closed and not any(
+                if not self._closed and not any(
                         q.pending for q in self._queues.values()):
-                    self._cv.wait()
+                    # every queue empty: the closed loop's turnaround
+                    # (ack -> client -> next POST) lands here
+                    with tracing.span("sched.starved", annotate=True):
+                        while not self._closed and not any(
+                                q.pending for q in self._queues.values()):
+                            self._cv.wait()
                 if self._closed and not any(
                         q.pending for q in self._queues.values()):
                     return
@@ -428,9 +433,13 @@ class IngestScheduler:
                 now = time.monotonic()
                 wait = (min(0.05, max(0.0, next_deadline - now))
                         if next_deadline is not None else 0.001)
+                held = (tracing.span("sched.coalesce", annotate=True)
+                        if next_deadline is not None
+                        else contextlib.nullcontext())
                 with self._cv:
                     if not self._closed:
-                        self._cv.wait(timeout=wait)
+                        with held:
+                            self._cv.wait(timeout=wait)
 
     def _run_round(self):
         """One DRR round: every queue earns a quantum; queues whose
@@ -598,7 +607,7 @@ class IngestScheduler:
                         "requests": len(batch), "records": total,
                         "bucket": self._bucket_for(total),
                         "merged_trace_ids": ",".join(merged_ids),
-                    }):
+                    }, annotate=True):
                         wl._run_merged(list(batch))
                     hold = time.monotonic() - t0
                     note = getattr(wl, "note_lock_hold", None)

@@ -38,7 +38,7 @@ from ..links import create_link_database
 from ..links.base import LinkDatabase
 from ..service.datasource import IncrementalDataSource
 from ..store.records import RecordStore
-from ..telemetry import memory
+from ..telemetry import memory, tracing
 from ..utils import faults
 from .listeners import ServiceMatchListener
 from .processor import Processor
@@ -300,7 +300,8 @@ class Workload:
             return
         mark = getattr(self.index, "mark_store_synced", None)
         if mark is not None:
-            mark(self.record_store.content_hash())
+            with tracing.span("ingest.stamp", annotate=True):
+                mark(self.record_store.content_hash())
 
     def _run_merged(self, work: List[_BatchRequest]) -> None:
         """Process queued requests as one batch (call with self.lock held).
@@ -327,32 +328,34 @@ class Workload:
             all_live: List[Record] = []
             any_deleted = False
             ok: List[_BatchRequest] = []
-            for req, records in zip(group, group_records):
-                put_done = False
-                try:
-                    if self.record_store is not None:
-                        self.record_store.put_many(records)
-                        # kill-differential site (ISSUE 10): store rows
-                        # durable, index/scoring/links not yet applied
-                        faults.check_crash("post_store_put")
-                        put_done = True
-                    deleted = [r for r in records if r.is_deleted()]
-                    for record in deleted:
-                        self.index.index(record)
-                    self._retract_links_for(deleted)
-                except Exception as e:  # store errors stay per-request
-                    if put_done:
-                        # the store committed rows the index will never
-                        # apply: latch the divergence so no later stamp
-                        # (this flush or any future batch) can mask it
-                        # (_mark_synced honors the latch)
-                        self._store_dirty = True
-                    req.error = e
-                    req.event.set()
-                    continue
-                any_deleted = any_deleted or bool(deleted)
-                all_live.extend(r for r in records if not r.is_deleted())
-                ok.append(req)
+            with tracing.span("ingest.store", annotate=True):
+                for req, records in zip(group, group_records):
+                    put_done = False
+                    try:
+                        if self.record_store is not None:
+                            self.record_store.put_many(records)
+                            # kill-differential site: store rows
+                            # durable, index/scoring/links not yet
+                            # applied
+                            faults.check_crash("post_store_put")
+                            put_done = True
+                        deleted = [r for r in records if r.is_deleted()]
+                        for record in deleted:
+                            self.index.index(record)
+                        self._retract_links_for(deleted)
+                    except Exception as e:  # store errors stay per-request
+                        if put_done:
+                            # the store committed rows the index will
+                            # never apply: latch the divergence so no
+                            # later stamp (this flush or any future batch)
+                            # can mask it (_mark_synced honors the latch)
+                            self._store_dirty = True
+                        req.error = e
+                        req.event.set()
+                        continue
+                    any_deleted = any_deleted or bool(deleted)
+                    all_live.extend(r for r in records if not r.is_deleted())
+                    ok.append(req)
             try:
                 with self._mesh_op_lock():
                     if any_deleted:
@@ -382,7 +385,8 @@ class Workload:
         for req in work:
             try:  # conversion errors stay per-request
                 datasource = self.datasources[req.dataset_id]
-                records = datasource.records_for_batch(req.entities)
+                with tracing.span("ingest.convert", annotate=True):
+                    records = datasource.records_for_batch(req.entities)
             except Exception as e:
                 req.error = e
                 req.event.set()

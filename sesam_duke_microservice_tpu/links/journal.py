@@ -53,6 +53,7 @@ import zlib
 from typing import List, Optional, Sequence, Tuple
 
 from .. import telemetry
+from ..telemetry import tracing
 from ..telemetry.env import env_str
 from ..utils import faults
 
@@ -367,7 +368,8 @@ class LinkJournal:
         Called BEFORE the batch is acknowledged — this write (plus the
         configured sync) IS the durability point."""
         payload = json.dumps(rows, separators=(",", ":")).encode("utf-8")
-        with self._lock:
+        with self._lock, tracing.span("links.journal_append",
+                                      annotate=True):
             seq = self._last_seq + 1
             frame = _frame(_KIND_BATCH, seq, payload)
             plan = faults.active()

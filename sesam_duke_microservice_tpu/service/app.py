@@ -993,7 +993,10 @@ class DukeRequestHandler(BaseHTTPRequestHandler):
             )
         return kind, workload, dataset_id, transform
 
-    def _handle_post_batch(self, m, body: bytes) -> None:
+    @staticmethod
+    def _parse_batch(body: bytes):
+        """The POST body as ``(entities, single)``: one JSON object or an
+        array of them."""
         try:
             payload = json.loads(body.decode("utf-8"))
         except (ValueError, UnicodeDecodeError):
@@ -1007,10 +1010,15 @@ class DukeRequestHandler(BaseHTTPRequestHandler):
         for entity in batch:
             if not isinstance(entity, dict):
                 raise _HttpError(400, "Batch elements must be JSON objects")
+        return batch, single
 
-        kind, workload, dataset_id, transform = self._validate_entity_path(m)
-        if not transform:
-            self._check_write_fence(kind, m.group(2), workload)
+    def _handle_post_batch(self, m, body: bytes) -> None:
+        with tracing.span("http.parse", annotate=True):
+            batch, single = self._parse_batch(body)
+            kind, workload, dataset_id, transform = \
+                self._validate_entity_path(m)
+            if not transform:
+                self._check_write_fence(kind, m.group(2), workload)
         sched = self.app.scheduler
         if sched is not None and not transform:
             # continuous microbatching (ISSUE 6): the scheduler coalesces

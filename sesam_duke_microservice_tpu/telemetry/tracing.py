@@ -38,7 +38,10 @@ and a list append — no locks on the span path (GIL-atomic appends, the
 registry's single-writer tolerance), no device syncs ever, and all
 exporter/digest work happens at retention time.  Device-timeline
 bridging (``annotate=True``) activates only while a ``jax.profiler``
-capture is live, so idle serving never touches jax from here.
+capture is live, so idle serving never touches jax from here.  Bridged
+spans reach the profiler as ``duke/<name>`` (``ANNOTATION_PREFIX``),
+with or without a request trace, and ``clock_anchor()`` ties the
+program's monotonic clock to the capture's timeline.
 """
 
 from __future__ import annotations
@@ -78,6 +81,8 @@ __all__ = [
     "trace_to_json",
     "set_device_annotations",
     "device_annotations_active",
+    "ANNOTATION_PREFIX",
+    "clock_anchor",
 ]
 
 
@@ -421,6 +426,18 @@ RECORDER = FlightRecorder()
 # bool read on the span path; jax is touched only while capturing.
 _ANNOTATE = False
 
+# THE rule that sets the program's annotations apart from the runtime's
+# own host events on the profiler timeline (``Transpose``,
+# ``np.asarray(jax.Array)``, ``PythonRefManager::CollectGarbage``): every
+# annotation this module enters is named ``duke/<span name>``.  Span
+# names in the flight recorder carry no prefix.
+ANNOTATION_PREFIX = "duke/"
+CLOCK_ANCHOR = "clock.anchor"
+# and each carries, as this event stat, the ``time.monotonic_ns()``
+# reading taken as it was entered: any capture then places program
+# times on its own clock, whoever started it
+CLOCK_STAT = "monotonic_ns"
+
 
 def set_device_annotations(enabled: bool) -> None:
     global _ANNOTATE
@@ -431,23 +448,51 @@ def device_annotations_active() -> bool:
     return _ANNOTATE
 
 
-def _enter_annotation(name: str):
+def _enter_annotation(name: str, monotonic_ns: int):
     try:
         import jax
 
-        ann = jax.profiler.TraceAnnotation(name)
+        ann = jax.profiler.TraceAnnotation(name,
+                                           **{CLOCK_STAT: monotonic_ns})
         ann.__enter__()
         return ann
     except Exception:
         return None
 
 
+def _exit_annotation(ann, exc_type=None, exc=None, tb=None) -> None:
+    try:
+        ann.__exit__(exc_type, exc, tb)
+    except Exception:
+        pass
+
+
+def clock_anchor() -> int:
+    """Tie the program's monotonic clock to the profiler's timeline.
+
+    Reads ``time.monotonic_ns()``, then emits a zero-length
+    ``duke/clock.anchor`` annotation that carries the reading and
+    returns it.  Call it while a capture is live: a program time ``t``
+    (a ``Span.start_ns``, a client's window start) then sits at
+    ``anchor_trace_ns + (t - reading)`` on the trace's clock, where
+    ``anchor_trace_ns`` is the anchor event's start in the capture.
+    Every other ``duke/`` annotation carries its own reading the same
+    way, so a capture with any program span in it ties the clocks."""
+    now = time.monotonic_ns()
+    ann = _enter_annotation(ANNOTATION_PREFIX + CLOCK_ANCHOR, now)
+    if ann is not None:
+        _exit_annotation(ann)
+    return now
+
+
 # -- span recording ----------------------------------------------------------
 
 class _SpanCtx:
     """The ``span()`` context manager as a slotted class: the unsampled
-    fast path stays one contextvar get (+ a set/reset pair and two
-    monotonic reads when a trace is active)."""
+    fast path stays one contextvar get and one bool read (+ a set/reset
+    pair and two monotonic reads when a trace is active).  Outside a
+    request trace an ``annotate=True`` span still enters its device
+    annotation while a capture is live, and records no ``Span``."""
 
     __slots__ = ("_name", "_attributes", "_annotate", "_span", "_token",
                  "_trace", "_ann")
@@ -465,6 +510,9 @@ class _SpanCtx:
     def __enter__(self) -> Optional[Span]:
         active = _ACTIVE.get()
         if active is None:
+            if _ANNOTATE and self._annotate:
+                self._ann = _enter_annotation(
+                    ANNOTATION_PREFIX + self._name, time.monotonic_ns())
             return None
         trace, parent_id = active
         s = Span(trace.trace_id, _new_span_id(), parent_id, self._name,
@@ -472,19 +520,17 @@ class _SpanCtx:
         self._span = s
         self._trace = trace
         self._token = _ACTIVE.set((trace, s.span_id))
-        if self._annotate and _ANNOTATE:
-            self._ann = _enter_annotation(self._name)
+        if _ANNOTATE and self._annotate:
+            self._ann = _enter_annotation(ANNOTATION_PREFIX + self._name,
+                                          s.start_ns)
         return s
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._ann is not None:
+            _exit_annotation(self._ann, exc_type, exc, tb)
         s = self._span
         if s is None:
             return False
-        if self._ann is not None:
-            try:
-                self._ann.__exit__(exc_type, exc, tb)
-            except Exception:
-                pass
         _ACTIVE.reset(self._token)
         s.end_ns = time.monotonic_ns()
         if exc_type is not None:
@@ -523,11 +569,11 @@ def add_span(name: str, start_ns: int, end_ns: int,
 
 def add_phase_spans(start_ns: int, retrieve_seconds: float,
                     score_seconds: float) -> None:
-    """The engines' shared retrieve/score span layout: both phases
-    interleave (per record on the host, per double-buffered block on the
-    device), so their spans carry the ACCUMULATED durations laid out
-    sequentially from the matching region's start — the timeline shows
-    where the batch's time went, not exact intervals."""
+    """The host engine's retrieve/score span layout: both phases
+    interleave per record, so their spans carry the ACCUMULATED
+    durations laid out sequentially from the matching region's start —
+    the timeline shows where the batch's time went, not exact intervals.
+    The device engine opens real spans per block instead."""
     r_end = start_ns + int(retrieve_seconds * 1e9)
     add_span("retrieve", start_ns, r_end, {"aggregate": True})
     add_span("score", r_end, r_end + int(score_seconds * 1e9),

@@ -17,9 +17,11 @@ Three levels:
   * **on-demand capture** (ISSUE 2) — ``start_capture(seconds)`` /
     ``POST /debug/profile?seconds=N`` opens a ``jax.profiler`` trace NOW
     for N seconds, no restart and no env preconfiguration.  While a
-    capture is live, tracing spans created with ``annotate=True``
-    (engine phases) also enter ``jax.profiler.TraceAnnotation`` so the
-    device timeline carries the request-trace names.
+    capture is live, tracing spans created with ``annotate=True`` (every
+    host step of a served microbatch) also enter
+    ``jax.profiler.TraceAnnotation`` as ``duke/<span name>``, and the
+    capture opens with a ``duke/clock.anchor`` that places the program's
+    monotonic clock on the trace's.
 """
 
 from __future__ import annotations
@@ -174,6 +176,9 @@ def start_capture(seconds: float, logdir: Optional[str] = None,
                      or tempfile.mkdtemp(prefix="duke-profile-"))
         profiler_start(directory)
         _tracing.set_device_annotations(True)
+        # the capture's duke/clock.anchor: a program monotonic time t
+        # sits at the anchor event's start + (t - anchor_monotonic_ns)
+        anchor_ns = _tracing.clock_anchor()
         timer = threading.Timer(seconds, stop_capture)
         timer.daemon = True
         _capture = {
@@ -183,6 +188,7 @@ def start_capture(seconds: float, logdir: Optional[str] = None,
             "deadline_unix": round(time.time() + seconds, 3),
             "until": time.monotonic() + seconds,
             "owner": owner,
+            "anchor_monotonic_ns": anchor_ns,
             "timer": timer,
         }
         timer.start()
@@ -190,7 +196,7 @@ def start_capture(seconds: float, logdir: Optional[str] = None,
                     "(owner=%s)", seconds, directory, owner)
         return {k: _capture[k] for k in
                 ("dir", "seconds", "started_unix", "deadline_unix",
-                 "owner")}
+                 "owner", "anchor_monotonic_ns")}
 
 
 def stop_capture() -> Optional[Dict[str, Any]]:

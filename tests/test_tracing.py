@@ -506,6 +506,180 @@ def test_profile_reset_rearms_trace_budget(server_url):
     assert profiling._traced_batches == 0
 
 
+# -- device annotations ------------------------------------------------------
+
+class _AnnotationLog:
+    """Recording stand-in for ``tracing._enter_annotation``: one
+    ``[name, enter_ns, exit_ns, reading]`` row per annotation entered,
+    ``reading`` being the monotonic time the annotation carries."""
+
+    def __init__(self):
+        self.rows = []
+        self._lock = threading.Lock()
+
+    def __call__(self, name, monotonic_ns):
+        row = [name, time.monotonic_ns(), None, monotonic_ns]
+        with self._lock:
+            self.rows.append(row)
+        return _AnnotationExit(row)
+
+    def named(self, name):
+        with self._lock:
+            return [r for r in self.rows if r[0] == name]
+
+
+class _AnnotationExit:
+    def __init__(self, row):
+        self.row = row
+
+    def __exit__(self, *exc):
+        self.row[2] = time.monotonic_ns()
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    log = _AnnotationLog()
+    monkeypatch.setattr(tracing, "_enter_annotation", log)
+    yield log
+    tracing.set_device_annotations(False)
+
+
+def test_annotated_span_outside_a_trace_enters_one_marked_annotation(
+        annotations):
+    """The dispatcher's waits run outside any request trace: with a
+    capture live the span still reaches the profiler, under the
+    program's ``duke/`` mark, and records no ``Span``."""
+    tracing.set_device_annotations(True)
+    assert tracing.current_context() is None
+    with tracing.span("sched.starved", annotate=True) as s:
+        assert s is None
+        assert tracing.current_context() is None
+    assert [r[0] for r in annotations.rows] == ["duke/sched.starved"]
+    assert annotations.rows[0][2] is not None
+    assert all(r[0].startswith(tracing.ANNOTATION_PREFIX)
+               for r in annotations.rows)
+
+
+def test_annotations_off_never_enter_one(annotations):
+    recorder = tracing.FlightRecorder(2, 2)
+    with tracing.span("sched.starved", annotate=True) as s:
+        assert s is None
+    with tracing.start_trace("root", sampled=True, recorder=recorder):
+        with tracing.span("encode", annotate=True) as s:
+            assert s is not None
+    assert annotations.rows == []
+
+
+def test_span_inside_a_trace_records_and_annotates(annotations):
+    recorder = tracing.FlightRecorder(2, 2)
+    tracing.set_device_annotations(True)
+    with tracing.start_trace("root", sampled=True,
+                             recorder=recorder) as root:
+        with tracing.span("persist", annotate=True):
+            pass
+        with tracing.span("links:assert_batch"):
+            pass
+    names = {s.name for s in recorder.get(root.trace_id).spans}
+    assert {"persist", "links:assert_batch"} <= names
+    assert [r[0] for r in annotations.rows] == ["duke/persist"]
+
+
+def test_clock_anchor_reads_before_its_annotation(annotations):
+    reading = tracing.clock_anchor()
+    (row,) = annotations.rows
+    name, enter_ns, exit_ns, carried = row
+    assert name == "duke/clock.anchor"
+    assert carried == reading
+    assert reading <= enter_ns <= exit_ns
+
+
+def test_capture_start_places_the_clock_anchor(annotations, monkeypatch):
+    monkeypatch.setattr(profiling, "profiler_start", lambda d: None)
+    monkeypatch.setattr(profiling, "profiler_stop", lambda: None)
+    try:
+        info = profiling.start_capture(30)
+    finally:
+        profiling.stop_capture()
+    (row,) = annotations.named("duke/clock.anchor")
+    assert info["anchor_monotonic_ns"] <= row[1]
+
+
+def _phase_counts(url):
+    import re
+
+    _, _, text = _request(url + "/metrics")
+    return {m.group(1): int(float(m.group(2))) for m in re.finditer(
+        r'duke_engine_phase_seconds_count\{kind="deduplication",'
+        r'workload="people",phase="(\w+)"\} (\S+)', text.decode())}
+
+
+def test_served_microbatch_names_every_host_step(tmp_path, monkeypatch,
+                                                 annotations):
+    """A real POST through the HTTP handler, the scheduler and the
+    device engine, with annotations on: every host step of the
+    microbatch enters its named annotation, retrieve ends before score
+    starts, both inside ``sched.microbatch``, and the phase histograms
+    count exactly what they count with annotations off."""
+    from sesam_duke_microservice_tpu.core.config import parse_config
+    from sesam_duke_microservice_tpu.service.app import DukeApp, serve
+    from test_service import CONFIG_XML
+
+    monkeypatch.setenv("MIN_RELEVANCE", "0.05")
+    for knob in ("DUKE_SCHEDULER", "DUKE_WRITE_BEHIND", "DUKE_JOURNAL"):
+        monkeypatch.setenv(knob, "1")  # pin against the CI =0 legs
+    xml = CONFIG_XML.replace(
+        "<DukeMicroService>", f'<DukeMicroService dataFolder="{tmp_path}">'
+    ).replace('<Deduplication name="people" link-database-type="in-memory">',
+              '<Deduplication name="people">')
+    app = DukeApp(parse_config(xml), backend="device", persistent=True)
+    server = serve(app, port=0, host="127.0.0.1")
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+    url = f"http://127.0.0.1:{server.server_address[1]}"
+    try:
+        def post(prefix):
+            body = json.dumps([
+                {"_id": f"{prefix}1", "name": f"ole {prefix} hansen",
+                 "email": f"{prefix}@x"},
+                {"_id": f"{prefix}2", "name": f"ole {prefix} hanse",
+                 "email": f"{prefix}@x"},
+            ]).encode()
+            status, _, _ = _request(
+                url + "/deduplication/people/crm", "POST", body,
+                {"Content-Type": "application/json"})
+            assert status == 200
+
+        post("off")
+        counts = [_phase_counts(url)]
+        assert annotations.rows == []
+        tracing.set_device_annotations(True)
+        post("on")
+        tracing.set_device_annotations(False)
+        counts.append(_phase_counts(url))
+    finally:
+        server.shutdown()
+        app.close()
+
+    steps = ("http.parse", "sched.microbatch", "ingest.convert",
+             "ingest.store", "ingest.stamp", "encode", "retrieve", "score",
+             "persist", "links.journal_append")
+    for step in steps:
+        assert annotations.named("duke/" + step), step
+    assert all(r[0].startswith("duke/") and r[2] is not None
+               for r in annotations.rows)
+    # each carries the monotonic time it was entered at, for the clock
+    assert all(r[3] <= r[1] for r in annotations.rows)
+    (retrieve,) = annotations.named("duke/retrieve")
+    (score,) = annotations.named("duke/score")
+    assert retrieve[2] <= score[1]
+    (batch,) = [r for r in annotations.named("duke/sched.microbatch")
+                if r[1] <= retrieve[1]]
+    assert batch[1] <= retrieve[1] and score[2] <= batch[2]
+    # one more observation per phase, as with annotations off
+    phases = ("encode", "retrieve", "score", "persist")
+    assert all(counts[0][p] == 1 for p in phases), counts
+    assert all(counts[1][p] == 2 for p in phases), counts
+
+
 def test_error_responses_carry_request_and_trace_ids(server_url):
     status, headers, _ = _request(server_url + "/no/such/path")
     assert status == 404
