@@ -6,7 +6,9 @@ XLA program per query block: the whole corpus lives on device as padded
 feature tensors (``ops.features``), a jitted blockwise scorer
 (``ops.scoring.build_corpus_scorer``) scores every query against every
 corpus row in chunks keeping a running top-K, and the host only finalizes
-the surviving K pairs per query.
+the surviving K pairs per query.  The scan stops at the corpus's valid
+high-water mark (``DeviceCorpus.valid_hwm``), not its capacity: the rows
+past it are all masked, so skipping them changes no result.
 
 Semantics contract (held to the host engine by differential tests in
 ``tests/test_device_matcher.py``):
@@ -116,6 +118,9 @@ _VALUE_SLOTS_MAX = env_int("DEVICE_VALUE_SLOTS_MAX", 8)
 # DEVICE_MAX_CHARS_CAP and truncate beyond it.
 _CHARS_CAP = env_int("DEVICE_MAX_CHARS_CAP", 1024)
 _DEMOTE_CHARS = env_int("DEVICE_DEMOTE_CHARS", 256)
+# dead-row runs a corpus remembers below its valid high-water mark
+# (DeviceCorpus._dead_runs): shortcuts only, the oldest go first
+_DEAD_RUNS_MAX = 64
 
 
 def query_buckets() -> tuple:
@@ -142,6 +147,10 @@ _BUCKET_CHILDREN = {
         telemetry.QUERY_PAD_ROWS.labels(bucket=str(b)))  # dukecheck: ignore[DK501] init-time pre-resolution
     for b in _QUERY_BUCKETS
 }
+_SCAN_ROWS_CHILDREN = tuple(
+    telemetry.DEVICE_SCAN_ROWS.labels(part=part)  # dukecheck: ignore[DK501] init-time pre-resolution
+    for part in ("scanned", "capacity")
+)
 _STREAM_SLICES_CHILD = telemetry.STREAM_APPEND_SLICES.single()
 
 
@@ -187,6 +196,16 @@ class DeviceCorpus:
         # 10M rows.  External mask mutators must recompute it
         # (snapshot_load does), same contract as _dirty_masks.
         self.live_rows = 0
+        # valid high-water mark: 1 + the last row with row_valid set, 0
+        # when none is — the host's copy of what the scorer bounds its
+        # scan by (ops.scoring.live_chunks), kept by append/tombstone
+        # under the live_rows contract.  _dead_runs remembers (lo, hi)
+        # runs of invalid rows below it, ascending, so a tombstone of the
+        # row under the mark walks back in O(1) amortised steps: without
+        # them a tail re-indexed again and again would be walked again
+        # and again.  They only shorten the walk; dropping one is safe.
+        self.valid_hwm = 0
+        self._dead_runs: List[Tuple[int, int]] = []
         self.feats: Dict[str, Dict[str, np.ndarray]] = {}
         self.row_valid = np.zeros((0,), dtype=bool)
         self.row_deleted = np.zeros((0,), dtype=bool)
@@ -283,6 +302,10 @@ class DeviceCorpus:
         self.row_group[lo:hi] = group
         self.row_ids.extend(ids)
         self.live_rows += int(n - np.asarray(deleted, dtype=bool).sum())
+        if self.valid_hwm < lo:
+            self._dead_runs.append((self.valid_hwm, lo))
+            del self._dead_runs[:-_DEAD_RUNS_MAX]
+        self.valid_hwm = hi
         old_size, self.size = self.size, self.size + n
         self._mutation_gen += 1
         if not self._dirty_full:
@@ -306,6 +329,27 @@ class DeviceCorpus:
         self.row_valid[row] = False
         self._mask_rows.append(int(row))
         self._mutation_gen += 1
+        if row == self.valid_hwm - 1:
+            hwm, runs = self.valid_hwm, self._dead_runs
+            while hwm > 0 and not self.row_valid[hwm - 1]:
+                hwm = runs.pop()[0] if runs and runs[-1][1] == hwm else hwm - 1
+            self.valid_hwm = hwm
+
+    def recount_masks(self) -> None:
+        """Recompute ``live_rows`` and ``valid_hwm`` from the host masks:
+        for code that writes ``row_valid``/``row_deleted`` outside
+        ``append``/``tombstone`` (snapshot_load)."""
+        valid = self.row_valid[: self.size]
+        self.live_rows = int((valid & ~self.row_deleted[: self.size]).sum())
+        rows = np.flatnonzero(valid)
+        self.valid_hwm = int(rows[-1]) + 1 if rows.size else 0
+        self._dead_runs = []
+
+    def live_chunks(self, chunk: int) -> int:
+        """Scan chunks the single-device scorer runs over this corpus:
+        the host's twin of ``ops.scoring.live_chunks`` on the device
+        mask."""
+        return -(-self.valid_hwm // chunk)
 
     def reserve(self, total_rows: int) -> None:
         """Pre-grow capacity to fit ``total_rows`` ahead of a sliced
@@ -1470,11 +1514,9 @@ class DeviceIndex(CandidateIndex):
         corpus.row_valid[: n] = row_valid
         corpus._dirty_masks = True
         # the direct mask overwrite above bypassed append/tombstone — the
-        # incremental live counter must be recomputed with it
-        live_count = int(
-            (np.asarray(row_valid) & ~np.asarray(row_deleted)).sum()
-        )
-        corpus.live_rows = live_count
+        # incremental live counter and high-water mark follow it
+        corpus.recount_masks()
+        live_count = corpus.live_rows
         # corpus tensors are assembled: stream them to HBM while the rest
         # of the restore (row-map wiring below, store/link bring-up in
         # build_workload, service startup) runs on the host
@@ -2225,6 +2267,13 @@ class _ScorerCache:
             record_cache_hit()
         return self._scorers[key]
 
+    def _scanned_rows(self, corpus: DeviceCorpus) -> int:
+        """Corpus rows one call of this cache's scorer scans: whole chunks
+        up to the valid high-water mark (``scan_topk``'s ``live_bound``;
+        never past the capacity, a whole number of chunks).  The mesh
+        cache overrides it with the capacity its full scan covers."""
+        return corpus.live_chunks(_CHUNK) * _CHUNK
+
     def _min_logit(self) -> float:
         from ..ops import scoring as S
 
@@ -2326,8 +2375,12 @@ class _ScorerCache:
         cfeats, cvalid, cdeleted, cgroup = corpus.device_arrays()
         args = (cfeats, cvalid, cdeleted, cgroup, query_group_j,
                 query_row_j, jnp.float32(min_logit))
+        scanned_child, capacity_child = _SCAN_ROWS_CHILDREN
+        scanned, capacity = self._scanned_rows(corpus), corpus.capacity
 
         def call(k):
+            scanned_child.inc(scanned)
+            capacity_child.inc(capacity)
             # AOT fast path (ISSUE 15): a deserialized/pre-built
             # executable registered for this exact shape skips the jit
             # trace entirely — a restarted process's first batch scores

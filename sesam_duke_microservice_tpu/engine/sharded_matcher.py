@@ -321,6 +321,10 @@ class _ShardedScorerCache(_MeshProgramLift, _ScorerCache):
             group_filtering=group_filtering,
         )
 
+    def _scanned_rows(self, corpus) -> int:
+        # every shard scans its whole slice (parallel.sharded)
+        return corpus.capacity
+
 
 class _ShardedAnnScorerCache(_MeshProgramLift, _AnnScorerCache):
     """ANN scorer cache over the mesh (parallel.ann_sharded program)."""

@@ -1,12 +1,14 @@
 """The device scoring program: per-property kernels + naive-Bayes combine.
 
 Assembles, for a given schema feature plan (ops.features.SchemaFeatures), a
-jitted function that scores a block of Q query records against the whole
-device-resident corpus in chunks, maintaining a running top-K per query.
+jitted function that scores a block of Q query records against the
+device-resident corpus in chunks, up to its last valid row, maintaining a
+running top-K per query.
 This replaces the reference hot loop (candidate fetch + per-pair comparator
 dispatch + Bayes fold, SURVEY.md section 3.2) with one XLA program:
 
-    for each corpus chunk (lax.scan, static trip count):
+    for each corpus chunk up to the live high-water mark (a while loop
+    whose trip count is computed on device, ``live_chunks``):
         sims  = per-property pairwise kernels        (ops.pairwise)
         probs = Duke's [low, high] similarity map    (per property)
         logit = sum of clamped log-odds              (naive Bayes, 0.5 prior)
@@ -1327,6 +1329,18 @@ class ScoreResult:
     count_above: np.ndarray  # (Q,) candidates whose optimistic prob clears min threshold
 
 
+def live_chunks(corpus_valid, chunk: int):
+    """Scan chunks up to the corpus's live high-water mark: ceil((last
+    index where ``corpus_valid`` is True, + 1) / chunk), 0 when no row is
+    valid.  A traced int32 scalar, so the trip count it bounds never
+    changes a compiled shape.  The host keeps the same number per corpus
+    (``engine.device_matcher.DeviceCorpus.live_chunks``)."""
+    cap = corpus_valid.shape[0]
+    rows = jnp.max(jnp.where(corpus_valid,
+                             jnp.arange(1, cap + 1, dtype=jnp.int32), 0))
+    return (rows + (chunk - 1)) // chunk
+
+
 def scan_topk(
     pair_logits: Callable,
     qfeats,
@@ -1343,6 +1357,7 @@ def scan_topk(
     group_filtering: bool,
     row_offset=0,
     init=None,
+    live_bound: bool = False,
 ):
     """The blockwise scan core: scores Q queries against a (local) corpus.
 
@@ -1354,11 +1369,20 @@ def scan_topk(
     ``init`` seeds the running (top_logit, top_index, count) carry — the
     ring scorer (parallel.ring) threads a query block's accumulated top-K
     through successive corpus shards with it.
+
+    ``live_bound`` stops the scan after the last chunk holding a valid row
+    (``live_chunks``) instead of covering the whole capacity.  The rows it
+    skips are ones ``candidate_mask`` drops, which add nothing to the count
+    and, since ``lax.top_k`` keeps the lower position on ties and every
+    empty carry slot already holds (NEG_INF, -1), nothing to the top-K: the
+    outputs are bit-identical to the full scan.  The single-device scorer
+    sets it; the mesh scorers keep the full scan, since rows fill shard 0
+    first and the last shard's full scan would set the pace anyway.
     """
     first = next(iter(qfeats.values()))
     q = first["valid"].shape[0]
     cap = corpus_valid.shape[0]
-    nchunks = cap // chunk
+    nchunks = live_chunks(corpus_valid, chunk) if live_bound else cap // chunk
 
     if init is not None:
         init_logit, init_index, init_count = init
@@ -1367,7 +1391,7 @@ def scan_topk(
         init_index = jnp.full((q, top_k), -1, jnp.int32)
         init_count = jnp.zeros((q,), jnp.int32)
 
-    def body(carry, ci):
+    def body(ci, carry):
         top_logit, top_index, count = carry
         start = ci * chunk
         cf = jax.tree_util.tree_map(
@@ -1395,13 +1419,18 @@ def scan_topk(
         )
         top_logit, sel = lax.top_k(merged_logit, top_k)
         top_index = jnp.take_along_axis(merged_index, sel, axis=1)
-        return (top_logit, top_index, count), None
+        return top_logit, top_index, count
 
-    (top_logit, top_index, count), _ = lax.scan(
-        body, (init_logit, init_index, init_count),
-        jnp.arange(nchunks, dtype=jnp.int32),
-    )
-    return top_logit, top_index, count
+    if live_bound:
+        return lax.fori_loop(0, nchunks, body,
+                             (init_logit, init_index, init_count))
+
+    def scan_body(carry, ci):
+        return body(ci, carry), None
+
+    out, _ = lax.scan(scan_body, (init_logit, init_index, init_count),
+                      jnp.arange(nchunks, dtype=jnp.int32))
+    return out
 
 
 def gather_rows(tree, rows: jnp.ndarray):
@@ -1428,6 +1457,9 @@ def build_corpus_scorer(
 
     ``corpus_*`` arrays are padded to a capacity that is a multiple of
     ``chunk``; recompiles only when the capacity changes (doubling growth).
+    The scan stops at the corpus's live high-water mark (``scan_topk``
+    ``live_bound``): chunks past the last valid row are never scored, and
+    the bound is a traced scalar, so a growing corpus compiles nothing.
     ``query_row`` is each query's own corpus row (-1 when not indexed, e.g.
     http-transform) for self-pair exclusion; ``min_logit`` is
     logit(min(threshold, maybe_threshold)) minus the host-property bound.
@@ -1453,6 +1485,7 @@ def build_corpus_scorer(
             pair_logits, qfeats, corpus_feats, corpus_valid, corpus_deleted,
             corpus_group, query_group, query_row, min_logit,
             chunk=chunk, top_k=top_k, group_filtering=group_filtering,
+            live_bound=True,
         )
 
     return score

@@ -107,6 +107,17 @@ QUERY_PAD_ROWS = GLOBAL.counter(
     "Padding rows added to reach the block's bucket size",
     ("bucket",), locked=False,
 )
+# Same unlocked discipline, children pre-resolved in device_matcher: each
+# brute-force scorer call (K-escalation re-runs included) adds the rows
+# its program scans and the corpus capacity it could have scanned.
+DEVICE_SCAN_ROWS = GLOBAL.counter(
+    "duke_device_scan_rows_total",
+    "Corpus rows the brute-force device scorer scanned per call "
+    "(part=scanned: up to the valid high-water mark on one device, the "
+    "whole capacity on a mesh) and the capacity it covered "
+    "(part=capacity)",
+    ("part",), locked=False,
+)
 SCORER_ESCALATIONS = GLOBAL.counter(
     "duke_scorer_escalations_total",
     "K/C-escalation re-runs of the device scoring program",
