@@ -2,48 +2,42 @@
 
 It imports nothing of the program.  It reads the configuration's
 service XML itself (``parse_service``): for each property its record
-column, its comparator (a module under ``perf/comparators/``) and its
-low and high probabilities, and the schema's threshold.  The score is Duke's (Processor.compare, PropertyImpl):
+column, that column's cleaner if it has one (a module under
+``perf/cleaners/``), its comparator (a module under
+``perf/comparators/``) and its low and high probabilities, and the
+schema's threshold.  The score is Duke's (Processor.compare,
+PropertyImpl):
 
     p = (high - 0.5) * sim**2 + 0.5   if sim >= 0.5 else low
     prob = 0.5, folded with every property's p by
     bayes(a, b) = a*b / (a*b + (1-a)*(1-b))
 
 in the order the schema lists the properties; a property with no value
-on either side is skipped.  ``dtype`` is numpy's float64 for the
-reference and float32 for the control.
+on either side, before or after cleaning, is skipped.  ``dtype`` is
+numpy's float64 for the reference and float32 for the control.
 """
 
 from __future__ import annotations
 
-import importlib.util
 import math
-import os
 import xml.etree.ElementTree as ET
 
 import numpy as np
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-
-_COMPARATORS = {}
+import plugins
 
 
-def comparator(name: str):
-    mod = _COMPARATORS.get(name)
-    if mod is None:
-        path = os.path.join(HERE, "comparators", f"{name}.py")
-        spec = importlib.util.spec_from_file_location(
-            f"perf_comparator_{name}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        _COMPARATORS[name] = mod
-    return mod
+def _file_name(text: str) -> str:
+    """The file a comparator or cleaner named in the XML is read from."""
+    return text.strip().lower()
 
 
 def parse_service(xml: str) -> dict:
     """The one workload of a configuration's service XML: its kind, name,
     link mode, datasets in group order, threshold, and for each property
-    the record column it reads, its comparator and its probabilities."""
+    the record column it reads, that column's cleaner (None without one),
+    its comparator and its probabilities.  Every data source has to feed
+    a property from the same column through the same cleaner."""
     root = ET.fromstring(xml)
     (wl,) = [e for e in root if e.tag in ("Deduplication", "RecordLinkage")]
     duke = wl.find("duke")
@@ -53,10 +47,17 @@ def parse_service(xml: str) -> dict:
             if param.get("name") == "dataset-id":
                 datasets.append(param.get("value"))
         for col in src.findall("column"):
-            columns[col.get("property")] = col.get("name")
+            cleaner = col.get("cleaner")
+            fed = (col.get("name"), cleaner and _file_name(cleaner))
+            prop = col.get("property")
+            if columns.setdefault(prop, fed) != fed:
+                raise ValueError(
+                    f"property {prop!r} is fed as {columns[prop]} and as "
+                    f"{fed} (column, cleaner): the reference scores one")
     schema = duke.find("schema")
-    props = [{"column": columns[p.findtext("name")],
-              "comparator": p.findtext("comparator").strip().lower(),
+    props = [{"column": columns[p.findtext("name")][0],
+              "cleaner": columns[p.findtext("name")][1],
+              "comparator": _file_name(p.findtext("comparator")),
               "low": float(p.findtext("low")),
               "high": float(p.findtext("high"))}
              for p in schema.findall("property")]
@@ -71,18 +72,27 @@ def parse_service(xml: str) -> dict:
     }
 
 
+def _cleaned(value, clean):
+    """Duke's value after its column's cleaner; None or empty drops it."""
+    return clean(value) if value and clean else value
+
+
 class Schema:
     def __init__(self, service: dict):
         self.threshold = service["threshold"]
-        self.props = [(p["column"], comparator(p["comparator"]),
-                       p["low"], p["high"]) for p in service["properties"]]
+        self.props = [
+            (p["column"],
+             p["cleaner"] and plugins.load("cleaners", p["cleaner"]).clean,
+             plugins.load("comparators", p["comparator"]), p["low"],
+             p["high"]) for p in service["properties"]]
 
     def score(self, r1: dict, r2: dict, dtype=np.float64) -> float:
         f = dtype
         half, one = f(0.5), f(1.0)
         prob = half
-        for column, comp, low, high in self.props:
-            v1, v2 = r1.get(column), r2.get(column)
+        for column, clean, comp, low, high in self.props:
+            v1 = _cleaned(r1.get(column), clean)
+            v2 = _cleaned(r2.get(column), clean)
             if not v1 or not v2:
                 continue
             sim = f(comp.compare(v1, v2))
@@ -113,17 +123,18 @@ class Corpus:
         self.keys = list(keys)
         self.records = list(records)
         self.columns = []
-        for column, comp, low, high in schema.props:
-            vals = [r.get(column) or "" for r in self.records]
+        for column, clean, comp, low, high in schema.props:
+            vals = [_cleaned(r.get(column), clean) or ""
+                    for r in self.records]
             vec = getattr(comp, "Column", None)
             self.columns.append(vec(vals) if vec else None)
 
     def matches(self, query: dict, margin: float, dtype=np.float64):
         """{row key: score} of every row scoring above threshold + margin."""
         bound = np.zeros(len(self.records))
-        for (column, comp, low, high), col in zip(self.schema.props,
-                                                  self.columns):
-            v = query.get(column)
+        for (column, clean, comp, low, high), col in zip(self.schema.props,
+                                                         self.columns):
+            v = _cleaned(query.get(column), clean)
             if not v:
                 continue
             hi = math.log(high / (1.0 - high))

@@ -12,7 +12,8 @@ process that never imports JAX, over loopback HTTP.
 A run: refuse without the chip; build the service with a fresh data
 folder; preload the deployment's corpus through the workload's store and
 index without scoring it; warm up on the cell's own traffic until a
-round compiles nothing; then the window.  With ``--trace 1`` the window
+round compiles nothing, and for at least ``WARM_ROUNDS_MIN`` rounds;
+then the window.  With ``--trace 1`` the window
 is traced and the per-layer metrics are read (``perf/layer_metrics/``);
 with ``--trace 0`` the end-to-end ones (``perf/end_to_end/``).  After the
 window the service is closed and the links it served are held against
@@ -27,7 +28,7 @@ import time
 T_START = time.monotonic()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
+import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -43,6 +44,7 @@ RUN_DIR = os.path.join(PERF, ".run")
 sys.path[:0] = [PERF, ROOT]
 
 import gen  # noqa: E402
+import plugins  # noqa: E402
 from compare import compare, is_correct  # noqa: E402
 from reference import parse_service  # noqa: E402
 
@@ -54,6 +56,11 @@ SMALL_SHAPE_KNOBS = (
     "DUKE_TPU_PALLAS",
 )
 WARM_ROUNDS_MAX = 8
+# every run posts at least this many warm-up rounds, a cold checkout's
+# first run and a warm one alike: each re-posted record appends a device
+# row, and the scan reads up to the last live row, so the window's work
+# depends on how many records the warm-up posted (PERF.md)
+WARM_ROUNDS_MIN = 3
 PREWARM_THREAD = "scorer-prewarm"   # the program's background compiler
 
 
@@ -87,12 +94,7 @@ def reported(bench: dict, cell: dict, section: str):
 
 
 def reader(kind: str, name: str):
-    path = os.path.join(PERF, kind, f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"perf_{kind}_{name}".replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return plugins.load(kind, name).read
 
 
 # -- /metrics text -------------------------------------------------------------
@@ -254,6 +256,31 @@ class BackendCompiles:
             self.count += 1
 
 
+class GcPauses:
+    """The interpreter's garbage collections and their pauses, by
+    generation (a log line only: a pause stops the server's host steps)."""
+
+    def __init__(self):
+        self.count = [0, 0, 0]
+        self.seconds = [0.0, 0.0, 0.0]
+        self._t = None
+        gc.callbacks.append(self.on_gc)
+
+    def on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t = time.perf_counter()
+        elif self._t is not None:
+            self.count[info["generation"]] += 1
+            self.seconds[info["generation"]] += time.perf_counter() - self._t
+            self._t = None
+
+    def snapshot(self):
+        return list(self.count), list(self.seconds)
+
+    def close(self) -> None:
+        gc.callbacks.remove(self.on_gc)
+
+
 def join_prewarm() -> None:
     for t in threading.enumerate():
         if t.name == PREWARM_THREAD:
@@ -334,6 +361,7 @@ def run_cell(name: str, seed: int, seconds: int, trace: bool, *,
         json.dump({"config": config, "traffic": traffic, "seed": seed,
                    "seconds": seconds, "report": report_path}, f)
     backend = BackendCompiles()
+    pauses = GcPauses()
     client = ClientProcess(plan_path)
     server = None
     try:
@@ -348,6 +376,9 @@ def run_cell(name: str, seed: int, seconds: int, trace: bool, *,
         def compiles():
             return (server.metric("duke_jit_compiles_total"), backend.count)
 
+        def escalations():
+            return server.metric("duke_scorer_escalations_total")
+
         before = compiles()
         for n in range(1, WARM_ROUNDS_MAX + 1):
             answer = client.ask({"cmd": "warm", "port": server.port})
@@ -357,10 +388,14 @@ def run_cell(name: str, seed: int, seconds: int, trace: bool, *,
                 f"{answer['failed']} failed; program compiles "
                 f"(duke_jit_compiles_total) {now[0] - before[0]}, XLA "
                 f"backend compiles {now[1] - before[1]}")
-            if now == before:
+            if now == before and n >= WARM_ROUNDS_MIN:
                 break
             before = now
 
+        # a full collection now, so that the window's collections fall
+        # at the same points of its work in every run
+        gc.collect()
+        esc_before, gc_before = escalations(), pauses.snapshot()
         if trace:
             from sesam_duke_microservice_tpu.telemetry import tracing
 
@@ -374,6 +409,7 @@ def run_cell(name: str, seed: int, seconds: int, trace: bool, *,
         else:
             client.ask({"cmd": "window"})
         after = compiles()
+        esc_after, gc_after = escalations(), pauses.snapshot()
         client.ask({"cmd": "report"})
         memory_peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                           for d in devices)
@@ -383,11 +419,16 @@ def run_cell(name: str, seed: int, seconds: int, trace: bool, *,
         if server is not None:
             server.close()
         client.close()
+        pauses.close()
 
     with open(report_path) as f:
         report = json.load(f)
     log(f"compiles inside the window: duke_jit_compiles_total "
         f"{after[0] - before[0]}, XLA backend compiles {after[1] - before[1]}")
+    log(f"scorer escalations inside the window: {esc_after - esc_before}")
+    log("garbage collections inside the window, generations 0/1/2: "
+        f"{[a - b for a, b in zip(gc_after[0], gc_before[0])]}, pauses s "
+        f"{[a - b for a, b in zip(gc_after[1], gc_before[1])]}")
     late = [p["send"] - p["due"] for p in report["posts"]
             if p["phase"] == "window"]
     log(f"generator lateness (send - due), s: p50 {percentile(late, 50)} "

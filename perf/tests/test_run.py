@@ -53,6 +53,17 @@ def test_tiny_run_reaches_the_result_line(cell):
     assert not is_correct(control), control
 
 
+def test_every_run_posts_the_same_warm_up():
+    """A warm checkout's run posts as many warm-up rounds as a cold one's:
+    the window's scan starts at the same device row either way."""
+    run.run_cell("dedup-resync", 2**31 + 8, 2, False, require_tpu=False,
+                 overrides=TINY)
+    with open(os.path.join(run.RUN_DIR, "report.json")) as f:
+        phases = {p["phase"] for p in json.load(f)["posts"]}
+    rounds = {p for p in phases if p.startswith("warm-")}
+    assert len(rounds) >= run.WARM_ROUNDS_MIN, sorted(phases)
+
+
 def _run(monkeypatch, cell, target, replacement):
     monkeypatch.setattr(target[0], target[1], replacement)
     return run.run_cell(cell, 99, 4, False, require_tpu=False,

@@ -51,13 +51,23 @@ def test_seeds_differ_in_data_not_in_work(cell, config_file, mix):
 @pytest.mark.parametrize("cell,config_file,mix", cells())
 def test_every_offset_meets_the_duplicates_at_their_share(cell, config_file,
                                                            mix):
-    """The generator's duplicates are its last ids; a source's order
-    spreads them, so the first POST of every pipe holds about the
-    corpus's share of them, whatever the seed's offset."""
+    """A source's order spreads the generator's duplicates (which it may
+    make last), so the first POST of every pipe holds about the corpus's
+    share of them, whatever the seed's offset."""
     config = load("..", config_file)
-    dup_rate = config["data"]["dup_rate"]
+    config["data"]["records"] = 4000
+    module = gen.generator(config)
+
+    def share(rows):
+        return sum(module.is_duplicate(r["_id"], config["data"])
+                   for r in rows) / len(rows)
+
     for seed in (7, 8, 2**31 + 5):
+        corpus = [r for rows in gen.corpus(config, seed).values()
+                  for r in rows]
+        want = share(corpus)
+        assert 0 < want < 1, (seed, want)
+        if "dup_rate" in config["data"]:
+            assert abs(want - config["data"]["dup_rate"]) < 1e-3, want
         for p, _ in zip(posts(config_file, mix, seed)[::2], range(4)):
-            ids = [int(e["_id"][1:]) for e in p[1]]
-            share = sum(i >= 4000 * (1 - dup_rate) for i in ids) / len(ids)
-            assert abs(share - dup_rate) < 0.06, (seed, share)
+            assert abs(share(p[1]) - want) < 0.06, (seed, share(p[1]), want)
