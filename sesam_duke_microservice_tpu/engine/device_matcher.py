@@ -118,6 +118,13 @@ _VALUE_SLOTS_MAX = env_int("DEVICE_VALUE_SLOTS_MAX", 8)
 # DEVICE_MAX_CHARS_CAP and truncate beyond it.
 _CHARS_CAP = env_int("DEVICE_MAX_CHARS_CAP", 1024)
 _DEMOTE_CHARS = env_int("DEVICE_DEMOTE_CHARS", 256)
+# Per-property q-gram set widths (GRAM_SET): the smallest power of two, at
+# least this, strictly greater than the most q-gram windows of any value
+# the property has held, capped by DEVICE_MAX_GRAMS.  "Strictly greater"
+# keeps a value's set below its slot count unless the cap clamps it, so
+# the finalize truncation residue (ops.scoring._dd_unsafe_mask) sees what
+# it saw at the fixed cap width.
+_GRAMS_MIN = 8
 # dead-row runs a corpus remembers below its valid high-water mark
 # (DeviceCorpus._dead_runs): shortcuts only, the oldest go first
 _DEAD_RUNS_MAX = 64
@@ -691,6 +698,12 @@ class DeviceIndex(CandidateIndex):
                 "with a device kernel (all configured comparators are "
                 "host-only); use the host backend for this schema"
             )
+        for spec in self.plan.device_props:
+            if spec.kind == F.GRAM_SET:
+                spec.max_grams = min(_GRAMS_MIN, F.MAX_GRAMS)
+        # batches that widened a q-gram set (single writer: the workload
+        # lock; /metrics duke_device_set_width_grows_total reads it)
+        self.set_width_grows = 0
         self.corpus = self._make_corpus(self.plan, v)
         self.records: Dict[str, Record] = {}     # id -> live record
         # incremental content digest of ``records`` (same per-record
@@ -789,10 +802,17 @@ class DeviceIndex(CandidateIndex):
 
         specs = []
         for spec in self.plan.device_props:
+            sized = {}
             v = self._sized_slots(spec, records)
-            specs.append(
-                replace(spec, values_per_record=v) if v != spec.v else spec
-            )
+            if v != spec.v:
+                sized["values_per_record"] = v
+            if spec.kind == F.GRAM_SET:
+                # a probe's longer value rides a wider query set instead
+                # of truncating (the tile kernel pads the corpus side)
+                g = self._sized_grams(spec, self._grams_needed(spec, records))
+                if g != spec.grams:
+                    sized["max_grams"] = g
+            specs.append(replace(spec, **sized) if sized else spec)
         return F.SchemaFeatures(
             device_props=specs, host_props=self.plan.host_props
         )
@@ -1003,8 +1023,41 @@ class DeviceIndex(CandidateIndex):
             width *= 2
         return min(width, _CHARS_CAP)
 
+    @staticmethod
+    def _grams_needed(spec, records: Sequence[Record]) -> int:
+        """The most q-gram windows of any of the property's values in
+        ``records`` (``len - q + 1``, 1 for a non-empty value shorter
+        than q): an upper bound on its distinct q-grams."""
+        q = int(getattr(spec.comparator, "q", 2))
+        longest = max(
+            (len(val) for r in records for val in r.get_values(spec.name)),
+            default=0,
+        )
+        return max(longest - q + 1, 1) if longest else 0
+
+    def _sized_grams(self, spec, need: int) -> int:
+        """Power-of-two set width strictly greater than ``need`` q-gram
+        windows, at least the current width, clamped to DEVICE_MAX_GRAMS
+        (warns once per property on clamp)."""
+        cap = F.MAX_GRAMS
+        if need >= cap:
+            key = f"grams:{spec.name}"
+            if key not in self._cap_warned:
+                self._cap_warned.add(key)
+                logger.warning(
+                    "property %r has a value of %d q-gram windows; device "
+                    "pruning sees its first %d distinct q-grams "
+                    "(DEVICE_MAX_GRAMS; host finalization stays exact)",
+                    spec.name, need, cap,
+                )
+        width = spec.grams
+        while width <= need and width < cap:
+            width *= 2
+        return min(width, cap)
+
     def _maybe_grow_value_slots(self, records: Sequence[Record]) -> None:
-        """Grow per-property value slots AND char widths to fit the batch.
+        """Grow per-property value slots, char widths AND q-gram set
+        widths to fit the batch.
 
         Duke scores the max over *all* value pairs per property
         (IncrementalDataSource.java:69-73 feeds multi-values), and its
@@ -1018,7 +1071,7 @@ class DeviceIndex(CandidateIndex):
         PER PROPERTY: one long-text field rides the wide (or scan-DP)
         kernels alone while short fields keep the one-word Myers path.
         """
-        grew = False
+        grew = grew_sets = False
         demote = []
         for spec in self.plan.device_props:
             if self._auto_value_slots:
@@ -1035,9 +1088,17 @@ class DeviceIndex(CandidateIndex):
                 if width > spec.chars:
                     spec.max_chars = width
                     grew = True
+            if spec.kind == F.GRAM_SET:
+                width = self._sized_grams(
+                    spec, self._grams_needed(spec, records))
+                if width > spec.grams:
+                    spec.max_grams = width
+                    grew_sets = True
         if demote and self._demote_to_host(demote):
             grew = True
-        if grew:
+        if grew_sets:
+            self.set_width_grows += 1
+        if grew or grew_sets:
             self._rebuild_corpus()
 
     def _demote_to_host(self, specs) -> bool:
@@ -1344,6 +1405,10 @@ class DeviceIndex(CandidateIndex):
                     [s.chars for s in self.plan.device_props],
                     dtype=np.int64,
                 ),
+                __set_widths=np.array(
+                    [s.grams for s in self.plan.device_props],
+                    dtype=np.int64,
+                ),
                 # surviving device properties (r4): a plan that demoted a
                 # long-text property to host scoring persists that choice,
                 # so a restart re-demotes instead of rejecting the
@@ -1442,6 +1507,21 @@ class DeviceIndex(CandidateIndex):
                             "schema", "char widths changed")
                 else:
                     widths = [s.chars for s in self.plan.device_props]
+                # per-property q-gram set widths: the saved tensors hold
+                # their width; a plan already wider than the snapshot
+                # (or a cap below it) must not adopt them
+                if "__set_widths" not in data.files:
+                    return self._snapshot_reject(
+                        "schema", "missing __set_widths")
+                set_widths = [int(x) for x in data["__set_widths"]]
+                if len(set_widths) != len(self.plan.device_props):
+                    return self._snapshot_reject(
+                        "schema", "set-width count mismatch")
+                if any(w < s.grams or w > F.MAX_GRAMS
+                       for s, w in zip(self.plan.device_props, set_widths)
+                       if s.kind == F.GRAM_SET):
+                    return self._snapshot_reject(
+                        "schema", "q-gram set widths differ from the plan's")
                 # record CONTENT hash, not just the id set: an id-set check
                 # would accept a snapshot predating an in-place record
                 # update that only the store persisted (crash before the
@@ -1496,8 +1576,8 @@ class DeviceIndex(CandidateIndex):
             logger.exception("snapshot load failed; replaying from store")
             return self._snapshot_reject("corrupt", repr(e))
 
-        # every check passed — only now adopt the snapshot's value-slot
-        # and char widths (a rejected snapshot must leave the plan
+        # every check passed — only now adopt the snapshot's value-slot,
+        # char and set widths (a rejected snapshot must leave the plan
         # untouched)
         if self._auto_value_slots:
             for spec, v in zip(self.plan.device_props, slots):
@@ -1505,6 +1585,9 @@ class DeviceIndex(CandidateIndex):
         if self._auto_chars:
             for spec, w in zip(self.plan.device_props, widths):
                 spec.max_chars = w
+        for spec, w in zip(self.plan.device_props, set_widths):
+            if spec.kind == F.GRAM_SET:
+                spec.max_grams = w
         corpus = self.corpus
         n = len(row_ids)
         rows = corpus.append(
@@ -1714,7 +1797,7 @@ def _plan_fingerprint(plan) -> list:
     program; thresholds ride as the runtime ``min_logit`` argument and
     deliberately do NOT key the cache)."""
     return [
-        [s.name, s.kind, s.v, s.chars, s.low, s.high,
+        [s.name, s.kind, s.v, s.chars, s.grams, s.low, s.high,
          _fp_value(s.comparator)]
         for s in plan.device_props
     ]
@@ -1929,7 +2012,8 @@ class _ScorerCache:
         cap = max(self.index.corpus.capacity, self._min_warm_cap())
         key = (
             cap,
-            tuple((s.v, s.chars) for s in self.index.plan.device_props),
+            tuple((s.v, s.chars, s.grams)
+                  for s in self.index.plan.device_props),
             bool(group_filtering),
         )
         prev = self._warmed
@@ -1948,7 +2032,7 @@ class _ScorerCache:
             # (the key has no capacity facet).
             self._rebind_shared_ladder(group_filtering)
         elif prev is not None and prev[1:] != key[1:]:
-            # the PLAN moved (value-slot/char growth, demotion, filtering
+            # the PLAN moved (value-slot/char/set growth, demotion, filtering
             # flip): every registered executable was built for the old
             # tensor shapes, and its (k, gf, from_rows, cap, bucket) akey
             # would otherwise BLOCK the load pass from refilling that

@@ -155,7 +155,8 @@ def _plan_key(plan) -> tuple:
     # never be garbage-collected, so its id can never be reused by a
     # different-parameter comparator from a config reload.
     return tuple(
-        (s.name, s.kind, s.low, s.high, s.v, s.chars, id(s.comparator))
+        (s.name, s.kind, s.low, s.high, s.v, s.chars, s.grams,
+         id(s.comparator))
         for s in plan.device_props
     )
 

@@ -194,7 +194,8 @@ def plan_fingerprint(plan, encoder=None) -> tuple:
 
     specs = tuple(
         (s.name, s.kind, int(s.values_per_record), int(s.chars),
-         type(s.comparator).__name__, getattr(s.comparator, "q", None))
+         int(s.grams), type(s.comparator).__name__,
+         getattr(s.comparator, "q", None))
         for s in plan.device_props
     )
     enc = None

@@ -28,6 +28,9 @@ from ..core.records import Record
 # beyond the padded width are truncated — documented in tests/test_ops.py;
 # the *value* axis auto-sizes to the data in engine.device_matcher, so
 # multi-valued records are not truncated below DEVICE_VALUE_SLOTS_MAX).
+# DEVICE_MAX_GRAMS is the CAP of a q-gram set's width: the device matcher
+# sizes each property's width from its values (engine.device_matcher
+# ``_sized_grams``); a plan no matcher sized runs at the cap.
 # Env-tunable: the CPU test backend uses smaller
 # shapes (tests/conftest.py) since it executes the kernels without an MXU.
 # MAX_CHARS defaults to 32 so edit distance rides the Myers bit-parallel
@@ -230,6 +233,10 @@ class PropertyFeatureSpec:
     # tensors (and rides the scan-DP fallback past MYERS_MAX_CHARS)
     # without dragging every short property off the 32-char Myers path
     max_chars: int = 0
+    # per-property q-gram set width (GRAM_SET): sized from the values'
+    # q-gram window counts in engine.device_matcher, so an 8-digit code
+    # compares 8 slots per side instead of the DEVICE_MAX_GRAMS cap
+    max_grams: int = 0
 
     @property
     def v(self) -> int:
@@ -238,6 +245,10 @@ class PropertyFeatureSpec:
     @property
     def chars(self) -> int:
         return self.max_chars or MAX_CHARS
+
+    @property
+    def grams(self) -> int:
+        return self.max_grams or MAX_GRAMS
 
 
 @dataclass
@@ -306,7 +317,8 @@ def extract_property(
             else None
         )
     elif kind == GRAM_SET:
-        grams = np.full((n, v, MAX_GRAMS), SET_PAD, dtype=np.int32)
+        G = spec.grams
+        grams = np.full((n, v, G), SET_PAD, dtype=np.int32)
         gram_count = np.zeros((n, v), dtype=np.int32)
         q = int(getattr(spec.comparator, "q", 2))
     elif kind == TOKEN_SET:
@@ -397,7 +409,7 @@ def extract_property(
             # value (replaces ~5 gram-substring Python objects + one
             # str.encode per window — the top ingest cost after hashing)
             gmat, gcounts = native.gram_set_batch(
-                [t[2] for t in flat], q, MAX_GRAMS, int(SET_PAD)
+                [t[2] for t in flat], q, G, int(SET_PAD)
             )
             grams[ii, kk] = gmat
             gram_count[ii, kk] = gcounts
@@ -411,7 +423,7 @@ def extract_property(
             for (i, k, _), gl in zip(flat, gram_lists):
                 ids = sorted(set(all_ids[pos:pos + len(gl)].tolist()))
                 pos += len(gl)
-                ids = ids[:MAX_GRAMS]
+                ids = ids[:G]
                 grams[i, k, : len(ids)] = ids
                 gram_count[i, k] = len(ids)
     elif kind == TOKEN_SET:

@@ -50,6 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..telemetry.env import env_flag
+from .features import SET_PAD
 
 _VMEM = pltpu.VMEM
 
@@ -700,16 +701,30 @@ def _intersect_tiles_padded(qg, qn2, cgt, cn2, *, tile_q, tile_c, interpret):
     )(qg, qn2, cgt, cn2)
 
 
+def _pad_set_width(sets, width: int):
+    """(N, G) sets padded with SET_PAD to (N, width) slots."""
+    if sets.shape[1] == width:
+        return sets
+    return jnp.pad(sets, ((0, 0), (0, width - sets.shape[1])),
+                   constant_values=SET_PAD)
+
+
 def set_intersection_tiles(qgrams, qn, cgrams, cn, *, interpret=None):
     """All-pairs |set_i ∩ set_j| -> (Q, C) int32.
 
     qgrams: (Q, G) int32 hashed ids (SET_PAD-padded); qn: (Q,) set sizes
-    cgrams: (C, G) int32; cn: (C,) — same layout as ops.features GRAM_SET /
+    cgrams: (C, G') int32; cn: (C,) — same layout as ops.features GRAM_SET /
     TOKEN_SET tensors.  Padded rows compute garbage counts that callers
-    mask via validity bits.
+    mask via validity bits.  The two widths may differ (an http-transform
+    probe's set can be wider than the corpus's): the narrower side is
+    padded, and its pad slots lie past its set size, which the kernel
+    never counts.
     """
-    q, g = qgrams.shape
+    q = qgrams.shape[0]
     c = cgrams.shape[0]
+    g = max(qgrams.shape[1], cgrams.shape[1])
+    qgrams = _pad_set_width(qgrams, g)
+    cgrams = _pad_set_width(cgrams, g)
     if interpret is None:
         interpret = _interpret()
     qg, qn2, cgt, cn2, tile_q, tile_c = _stage_pair_operands(

@@ -791,7 +791,7 @@ def _dd_plan_key(plan: "F.SchemaFeatures", extra: tuple) -> tuple:
         cmp = s.comparator
         key.append((
             s.name, s.kind, float(s.low), float(s.high), s.v, s.chars,
-            type(cmp).__name__,
+            s.grams, type(cmp).__name__,
             getattr(cmp, "formula", None),
             float(getattr(cmp, "prefix_scale", 0.0)),
             float(getattr(cmp, "boost_threshold", 0.0)),

@@ -22,6 +22,7 @@ import time
 from typing import Dict, Optional, Tuple
 
 from .. import __version__
+from ..ops.features import GRAM_SET
 from ..telemetry import (
     DEFAULT_LATENCY_BUCKETS,
     FamilySnapshot,
@@ -193,6 +194,8 @@ def make_app_collector(app):
         }
         rows_samples = []
         capacity_samples = []
+        set_width_samples = []
+        set_grow_samples = []
         shard_samples = []
         emb_samples = []
         ivf_cell_samples = []
@@ -293,6 +296,15 @@ def make_app_collector(app):
             if corpus is not None:
                 indexed = corpus.size
                 capacity_samples.append(("", labels, corpus.capacity))
+                # q-gram set widths, sized per property from the data
+                # (engine.device_matcher._sized_grams)
+                for spec in wl.index.plan.device_props:
+                    if spec.kind == GRAM_SET:
+                        set_width_samples.append((
+                            "", labels + (("property", spec.name),),
+                            float(spec.grams)))
+                set_grow_samples.append(
+                    ("", labels, float(wl.index.set_width_grows)))
                 mesh = getattr(corpus, "mesh", None)
                 if mesh is not None and mesh.size:
                     # record-axis sharded corpus: per-shard capacity (the
@@ -504,6 +516,16 @@ def make_app_collector(app):
             out.append(FamilySnapshot(
                 "duke_corpus_capacity_rows", "gauge",
                 "Pre-allocated device corpus capacity", capacity_samples))
+            out.append(FamilySnapshot(
+                "duke_device_set_width", "gauge",
+                "Slots per q-gram set in the device tensors, by property "
+                "(the intersection kernel compares width x width slots "
+                "per pair)", set_width_samples))
+            out.append(FamilySnapshot(
+                "duke_device_set_width_grows_total", "counter",
+                "Batches that widened a q-gram set property (each "
+                "rebuilds the corpus tensors and recompiles the scorer)",
+                set_grow_samples))
         if emb_samples:
             out.append(FamilySnapshot(
                 "duke_emb_bytes", "gauge",

@@ -34,6 +34,7 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from ..core.comparators import QGram
 from .decisions import _MonitorHist, classify
 from .env import env_flag, env_float, env_int
 from .registry import DEFAULT_LATENCY_BUCKETS, FamilySnapshot
@@ -68,7 +69,7 @@ def probe_interval_s() -> float:
 _ALPHA = "abcdefghijklmnopqrstuvwxyz"
 
 
-def _token(*parts: str) -> str:
+def _token(*parts: str, short: bool = False) -> str:
     """Deterministic two-word lowercase value for (pair, property, side).
 
     Letters only, so the standard cleaners (lowercase/trim) are identity
@@ -77,9 +78,16 @@ def _token(*parts: str) -> str:
     first stays an exact index token and the pair remains retrievable by
     token-level blocking (the inverted-index host backend) — the probe
     certifies the scoring/threshold path at a known edit distance, not
-    the recall limits of exact-token candidate search."""
+    the recall limits of exact-token candidate search.
+
+    ``short`` values (3 + 4 letters) serve q-gram properties: their 7
+    bigram windows fit the narrowest set width the device matcher sizes
+    to, so the shadow's plan, and with it the shared AOT ladder, stays
+    the user workload's."""
     h = hashlib.sha256("|".join(parts).encode("utf-8")).hexdigest()
     letters = [_ALPHA[int(c, 16) % 26] for c in h[:16]]
+    if short:
+        return "".join(letters[:3]) + " " + "".join(letters[3:7])
     return "".join(letters[:6]) + " " + "".join(letters[6:])
 
 
@@ -141,13 +149,18 @@ def derive_canaries(schema, ds_a, ds_b, compare) -> List[Canary]:
     # only properties both sides can express participate in canaries
     props = [p.name for p in schema.comparison_properties()
              if p.name in cols_a and p.name in cols_b]
+    qgram_props = {p.name for p in schema.comparison_properties()
+                   if isinstance(p.comparator, QGram)}
+
+    def token(pair_key: str, prop: str, side: str) -> str:
+        return _token(pair_key, prop, side, short=prop in qgram_props)
 
     def base(pair_key: str, cols: Dict[str, str], side: str) -> Dict[str, str]:
-        return {cols[p]: _token(pair_key, p, side) for p in props}
+        return {cols[p]: token(pair_key, p, side) for p in props}
 
     canaries: List[Canary] = []
 
-    same = {p: _token("identical", p, "ab") for p in props}
+    same = {p: token("identical", p, "ab") for p in props}
     canaries.append(Canary(
         "identical",
         {cols_a[p]: v for p, v in same.items()},
@@ -158,7 +171,7 @@ def derive_canaries(schema, ds_a, ds_b, compare) -> List[Canary]:
         for grade, perturb in (("near", _perturb_light),
                                ("far", _perturb_heavy)):
             key = f"{grade}-{prop}"
-            shared = {p: _token(key, p, "ab") for p in props}
+            shared = {p: token(key, p, "ab") for p in props}
             va = {cols_a[p]: v for p, v in shared.items()}
             vb = {cols_b[p]: v for p, v in shared.items()}
             vb[cols_b[prop]] = perturb(shared[prop])

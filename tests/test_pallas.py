@@ -394,6 +394,87 @@ def test_token_set_sim_tiles_vs_flat():
         np.testing.assert_allclose(got, want, atol=1e-6)
 
 
+def _sets_at_width(sizes_q, sizes_c, width, seed):
+    """Query and corpus (N, width) SET_PAD-padded sets of the given sizes,
+    all drawn from one universe of width + 2 ids, so full sets overlap
+    heavily and a 1-element set lies inside most full ones; the first
+    full corpus set equals the first full query set."""
+    from sesam_duke_microservice_tpu.ops.features import SET_PAD
+
+    rng = np.random.default_rng(seed)
+    universe = rng.choice(2**31 - 2, size=width + 2, replace=False) - 2**30
+
+    def draw(sizes):
+        sets = np.full((len(sizes), width), SET_PAD, np.int32)
+        for i, k in enumerate(sizes):
+            sets[i, :k] = np.sort(rng.choice(universe, size=k, replace=False))
+        return sets, np.asarray(sizes, np.int32)
+
+    qs, qn = draw(sizes_q)
+    cs, cn = draw(sizes_c)
+    cs[sizes_c.index(width)] = qs[sizes_q.index(width)]
+    return qs, qn, cs, cn
+
+
+def _flat_pairs(qg, qn, cg, cn):
+    nq, nc = qg.shape[0], cg.shape[0]
+    return (jnp.repeat(qg, nc, axis=0), jnp.repeat(qn, nc),
+            jnp.tile(cg, (nq, 1)), jnp.tile(cn, (nq,)))
+
+
+@pytest.mark.parametrize("width", [8, 16, 24])
+@pytest.mark.parametrize("formula", [None, "overlap", "jaccard", "dice"])
+def test_set_tiles_at_sized_widths_vs_flat(width, formula):
+    """Sets of 0, 1, width - 1 and width elements, and one tile that mixes
+    1-element and full sets, at the widths the device matcher sizes to
+    (the cap of the test shapes is 24): intersection counts, and the three
+    set formulas, equal the flat XLA path's."""
+    edge = [0, 1, width - 1, width]
+    qg, qn, cg, cn = map(jnp.asarray, _sets_at_width(
+        edge + [1, width] * 4, edge[::-1] + [width, 1] * 5, width,
+        seed=width))
+    nq, nc = qg.shape[0], cg.shape[0]
+    g1, n1, g2, n2 = _flat_pairs(qg, qn, cg, cn)
+    if formula is None:
+        got = pk.set_intersection_tiles(qg, qn, cg, cn, interpret=True)
+        want = pw.set_intersection_count(g1, n1, g2, n2).reshape(nq, nc)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert int(np.asarray(got).max()) == width   # full sets meet
+        return
+    equal = jnp.zeros((nq, nc), bool)
+    got = pk.set_sim_tiles(qg, qn, cg, cn, equal, formula=formula,
+                           interpret=True)
+    want = pw.qgram_sim(g1, n1, g2, n2, equal.reshape(-1),
+                        formula=formula).reshape(nq, nc)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_set_tiles_counts_do_not_depend_on_the_padded_width():
+    """The same sets padded to width 8 and to width 24 give identical
+    counts, and so do a width-8 side against a width-24 side (an
+    http-transform probe wider than the corpus)."""
+    from sesam_duke_microservice_tpu.ops.features import SET_PAD
+
+    sizes_q = [0, 1, 7, 8, 1, 8, 3, 8]
+    sizes_c = [8, 1, 0, 7, 8, 1, 5, 8, 2]
+    qg8, qn, cg8, cn = _sets_at_width(sizes_q, sizes_c, 8, seed=3)
+
+    def widen(sets):
+        pad = np.full((sets.shape[0], 16), SET_PAD, np.int32)
+        return jnp.asarray(np.concatenate([sets, pad], axis=1))
+
+    qn, cn = jnp.asarray(qn), jnp.asarray(cn)
+    at8 = np.asarray(pk.set_intersection_tiles(
+        jnp.asarray(qg8), qn, jnp.asarray(cg8), cn, interpret=True))
+    at24 = np.asarray(pk.set_intersection_tiles(
+        widen(qg8), qn, widen(cg8), cn, interpret=True))
+    mixed = np.asarray(pk.set_intersection_tiles(
+        widen(qg8), qn, jnp.asarray(cg8), cn, interpret=True))
+    np.testing.assert_array_equal(at8, at24)
+    np.testing.assert_array_equal(at8, mixed)
+    assert at8.max() == 8
+
+
 def test_scoring_program_set_kernels_pallas_wiring(monkeypatch):
     """The GRAM_SET/TOKEN_SET pallas branch agrees with the XLA path."""
     monkeypatch.setenv("DUKE_TPU_PALLAS", "0")

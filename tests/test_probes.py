@@ -118,6 +118,15 @@ USER_BATCH = [
     {"_id": "u3", "name": "bob jones", "email": "bob@example.no"},
 ]
 
+# the dedup stresstest's shape: 8-digit codes under QGram, whose q-gram
+# sets the device matcher sizes to its narrowest width
+QGRAM_XML = DEDUP_ONLY_XML.replace(
+    "<name>EMAIL</name>\n          <comparator>exact</comparator>",
+    "<name>EMAIL</name>\n          <comparator>qgram</comparator>")
+assert QGRAM_XML != DEDUP_ONLY_XML
+QGRAM_BATCH = [dict(r, email=code) for r, code in
+               zip(USER_BATCH, ("12345678", "12345678", "87654321"))]
+
 
 @pytest.fixture(autouse=True)
 def _clean_slate(monkeypatch):
@@ -451,5 +460,31 @@ class TestSharedLadder:
             assert telemetry.JIT_COMPILES.single().value == before
             state = app.prober._shadows[("deduplication", "people")].state
             assert state.probe_compiles == 0
+        finally:
+            app.close()
+
+    def test_qgram_probe_cycle_adds_zero_jit_compiles(self, monkeypatch):
+        """The same contract where a property's q-gram set width is sized
+        from the data: the canaries' q-gram values stay within the
+        narrowest set width, so the shadow's plan is still the user
+        workload's."""
+        monkeypatch.setenv("DEVICE_PREWARM", "1")
+        app = make_app(QGRAM_XML, backend="device")
+        try:
+            wl = app.deduplications["people"]
+            t = getattr(wl.index.scorer_cache, "_warm_thread", None)
+            if t is not None:
+                t.join(timeout=600)
+            app.scheduler.submit(
+                "deduplication", "people", "crm", list(QGRAM_BATCH))
+            before = telemetry.JIT_COMPILES.single().value
+            results = app.prober.run_cycle()
+            assert results[("deduplication", "people")]["ok"]
+            shadow = app.prober._shadows[("deduplication", "people")]
+            widths = [[s.grams for s in w.index.plan.device_props]
+                      for w in (wl, shadow.workload)]
+            assert widths[0] == widths[1]
+            assert telemetry.JIT_COMPILES.single().value == before
+            assert shadow.state.probe_compiles == 0
         finally:
             app.close()

@@ -114,11 +114,14 @@ def test_set_intersection_tiles(one_chip):
     def sds(shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
-    _compile(
-        lambda q, qn, c, cn: pk.set_intersection_tiles(q, qn, c, cn,
-                                                       interpret=False),
-        sds((BUCKET, 256)), sds((BUCKET,)), sds((CHUNK, 256)),
-        sds((CHUNK,)))
+    # 256: the widest set the tile branch takes; 8: the narrowest width
+    # the device matcher sizes a q-gram property to (an 8-digit code)
+    for width in (256, 8):
+        _compile(
+            lambda q, qn, c, cn: pk.set_intersection_tiles(
+                q, qn, c, cn, interpret=False),
+            sds((BUCKET, width)), sds((BUCKET,)), sds((CHUNK, width)),
+            sds((CHUNK,)))
 
 
 def test_retrieval_segmax(one_chip):
